@@ -28,11 +28,8 @@ def count_collectives(text: str) -> dict:
 
 
 def lower_allreduce_variants(n: int = 8, nbytes: int = 1 << 20) -> dict:
-    try:  # AxisType landed after jax 0.4.x, with a new AbstractMesh signature
-        mesh = AbstractMesh((n,), ("data",),
-                            axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:
-        mesh = AbstractMesh((("data", n),))
+    mesh = AbstractMesh((n,), ("data",),
+                        axis_types=(jax.sharding.AxisType.Auto,))
     elems = nbytes // 4
     x = jax.ShapeDtypeStruct((elems,), jnp.float32)
     m = float(nbytes)
@@ -45,12 +42,10 @@ def lower_allreduce_variants(n: int = 8, nbytes: int = 1 << 20) -> dict:
         "ring": lambda v: ring_all_reduce(v, "data"),
         "psum": lambda v: jax.lax.psum(v, "data"),
     }
-    from repro.collectives._compat import shard_map
-
     out = {}
     for name, fn in variants.items():
-        mapped = shard_map(fn, mesh=mesh, in_specs=P("data"),
-                           out_specs=P("data"), check_vma=False)
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                               out_specs=P("data"), check_vma=False)
         lowered = jax.jit(mapped).lower(
             jax.ShapeDtypeStruct((n * elems,), jnp.float32))
         out[name] = count_collectives(lowered.as_text())

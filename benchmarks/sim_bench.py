@@ -42,6 +42,8 @@ import json
 import sys
 import time
 
+from benchmarks.compile_cache import enable_compile_cache
+
 MB = 1024.0 ** 2
 DELTA = 1e-3
 
@@ -349,6 +351,7 @@ def main(argv=None) -> None:
     ap.add_argument("--min-jax-speedup", type=float, default=3.0,
                     help="min warm jax/numpy wall ratio on the jax tier")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from repro.core.batchsim_jax import jax_available
 

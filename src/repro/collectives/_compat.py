@@ -1,27 +1,11 @@
-"""JAX version compatibility shims (collectives, kernels, launch).
+"""Guarded jax import probe.
 
-The repo targets a range of JAX releases; everything that relies on an API
-whose home or name has moved across versions goes through here.  Current
-shims and the drift they triage:
-
-  axis_size              `jax.lax.axis_size` is new; old releases constant-
-                         fold `psum(1)` instead.
-  shard_map              moved from `jax.experimental.shard_map` to `jax.
-                         shard_map`, and `check_rep` was renamed `check_vma`.
-  pallas_compiler_params `jax.experimental.pallas.tpu.TPUCompilerParams` was
-                         renamed `CompilerParams` (jax 0.6); constructing it
-                         through this helper works on both spellings.
-  cost_analysis_dict     `Compiled.cost_analysis()` returned a one-element
-                         list of dicts on older releases and a flat dict on
-                         newer ones; normalize to a dict.
-
-Importing this module must never raise: the version probes are all guarded,
-so a CPU-only install without jax (or with a jax whose pallas extras are
-broken) can still import the pure-NumPy core — `repro.core.batchsim` and the
-JAX batch backend consult `HAS_JAX` / `require_jax()` instead of importing
-jax at module scope and letting kernels/-style import errors leak into the
-core path.  The individual shims raise a clear `ImportError` only when they
-are actually *called* without jax installed.
+Importing this module must never raise, so a CPU-only install without jax
+can still import the pure-NumPy core: `repro.core.batchsim` and the JAX
+batch backend consult `HAS_JAX` / `require_jax()` instead of importing jax
+at module scope and letting jax import errors leak into the core path.
+`require_jax` raises a clear `ImportError` only at the call that actually
+needs jax.
 """
 from __future__ import annotations
 
@@ -38,9 +22,9 @@ except Exception as exc:  # pragma: no cover - exercised on jax-less installs
 def require_jax(feature: str = "this feature"):
     """Return the jax module, raising an actionable error when absent.
 
-    Every shim below (and the JAX batch backend) funnels through this, so a
-    jax-less install fails at the *call* that genuinely needs jax with a
-    message naming the feature, never at import time.
+    The JAX batch backend funnels through this, so a jax-less install fails
+    at the *call* that genuinely needs jax with a message naming the
+    feature, never at import time.
     """
     if not HAS_JAX:  # pragma: no cover - exercised on jax-less installs
         raise ImportError(
@@ -48,85 +32,3 @@ def require_jax(feature: str = "this feature"):
             f"({JAX_IMPORT_ERROR!r}); install jax[cpu] or use the NumPy "
             f"backend") from JAX_IMPORT_ERROR
     return jax
-
-
-def jax_version() -> tuple[int, ...]:
-    """Installed jax version as an int tuple, () when jax is absent."""
-    if not HAS_JAX:
-        return ()
-    return tuple(int(p) for p in jax.__version__.split(".")[:3]
-                 if p.isdigit())
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, usable inside shard_map.
-
-    `jax.lax.axis_size` landed in newer releases; on older ones `psum(1)`
-    over the axis constant-folds to the same static value at trace time.
-    """
-    jx = require_jax("axis_size")
-    fn = getattr(jx.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return int(jx.lax.psum(1, axis_name))
-
-
-def shard_map(*args, **kwargs):
-    """`jax.shard_map` (new home) or `jax.experimental.shard_map` (old).
-
-    Also translates the `check_vma` kwarg to its pre-rename spelling
-    `check_rep` when the installed version only knows the old one.
-    """
-    jx = require_jax("shard_map")
-    fn = getattr(jx, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        return fn(*args, **kwargs)
-    except TypeError:
-        if "check_vma" not in kwargs:
-            raise
-        kwargs = dict(kwargs)
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-        return fn(*args, **kwargs)
-
-
-def pcast(x, axis_names, to: str = "varying"):
-    """`jax.lax.pcast` (varying-manual-axes casts, new jax) or identity.
-
-    Releases without the vma system (pre-`check_vma` shard_map) treat
-    replicated and varying values interchangeably inside shard_map, so the
-    cast is a no-op there.
-    """
-    jx = require_jax("pcast")
-    fn = getattr(jx.lax, "pcast", None)
-    if fn is None:
-        return x
-    return fn(x, axis_names, to=to)
-
-
-def pallas_compiler_params(**kwargs):
-    """TPU Pallas compiler params across the TPUCompilerParams rename.
-
-    jax >= 0.6 spells it `pltpu.CompilerParams`; 0.4/0.5 releases spell it
-    `pltpu.TPUCompilerParams` with the same fields (dimension_semantics, ...).
-    """
-    require_jax("pallas compiler params")
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """`Compiled.cost_analysis()` normalized to a flat dict.
-
-    Older jax returned `[{...}]` (one entry per computation), newer returns
-    `{...}`; either may be None on backends without cost analysis.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return dict(cost) if cost else {}

@@ -12,7 +12,6 @@ import jax.numpy as jnp
 
 from repro.core.schedules import Schedule
 
-from ._compat import axis_size as _axis_size
 from .bruck_rs_ag import bruck_all_gather, bruck_reduce_scatter
 
 
@@ -42,7 +41,7 @@ def _from_chunks(chunks: jax.Array, pad: int, shape, dtype) -> jax.Array:
 def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     """x: (n, ...) contributions; device i returns reduced block i.
     n - 1 unit-offset steps (neighbor-only: no congestion, minimal bytes)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] != n:
         raise ValueError(f"leading dim {x.shape[0]} != axis size {n}")
     if n == 1:
@@ -59,7 +58,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
 
 def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     """x: (...) local block; returns (n, ...): n - 1 unit-offset steps."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x[None]
     i = jax.lax.axis_index(axis_name)
@@ -74,7 +73,7 @@ def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
 
 def ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     """Bandwidth-optimal ring allreduce (sum), any shape."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     chunks, pad = _to_chunks(x, n)
@@ -96,7 +95,7 @@ def bruck_all_reduce(
 
     With schedules given, the permute chain follows the BRIDGE subring
     store-and-forward execution (see bruck_rs_ag docstring)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     chunks, pad = _to_chunks(x, n)

@@ -20,8 +20,6 @@ import numpy as np
 
 from repro.core.bruck import num_steps
 
-from ._compat import axis_size as _axis_size
-
 
 def _shift_perm(n: int, offset: int) -> list[tuple[int, int]]:
     """ppermute permutation: device i sends to (i + offset) mod n."""
@@ -30,7 +28,7 @@ def _shift_perm(n: int, offset: int) -> list[tuple[int, int]]:
 
 def bruck_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
     """Log-step all-to-all; x.shape[0] must equal the axis size."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] != n:
         raise ValueError(f"leading dim {x.shape[0]} != axis size {n}")
     if n == 1:

@@ -28,8 +28,6 @@ import numpy as np
 from repro.core.bruck import num_steps
 from repro.core.schedules import Schedule
 
-from ._compat import axis_size as _axis_size
-
 
 def _shift_perm(n: int, offset: int) -> list[tuple[int, int]]:
     return [(i, (i + offset) % n) for i in range(n)]
@@ -61,7 +59,7 @@ def bruck_reduce_scatter(x: jax.Array, axis_name: str,
     """x: (n, ...) local contributions; returns sum over devices of block i
     at device i (shape x.shape[1:]).  Equivalent to
     psum(x)[axis_index] but in log2(n) Bruck steps."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] != n:
         raise ValueError(f"leading dim {x.shape[0]} != axis size {n}")
     if n == 1:
@@ -88,7 +86,7 @@ def bruck_all_gather(x: jax.Array, axis_name: str,
     """x: (...) local block; returns (n, ...) with row p = device p's block.
     Equivalent to lax.all_gather(x, axis_name) in log2(n) Bruck steps with
     *decreasing* offsets 2^{s-1-k} (paper Section 3.5)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x[None]
     i = jax.lax.axis_index(axis_name)

@@ -27,8 +27,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.collectives._compat import pallas_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -163,7 +161,7 @@ def flash_attention_fwd_lse(q, k, v, *, scale: float, causal: bool,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

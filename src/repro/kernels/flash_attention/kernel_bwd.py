@@ -23,8 +23,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.collectives._compat import pallas_compiler_params
-
 
 def _mask_and_run(causal, window, off, sk, block_q, block_k, qi, ki):
     q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32,
@@ -177,7 +175,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale, causal, window,
         ],
         out_shape=[jax.ShapeDtypeStruct((b * h, sk_p, d), q.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32)] * 2,
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="bridge_flash_bwd_dkv",
@@ -193,7 +191,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, scale, causal, window,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, bq, a: (bh, bq, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="bridge_flash_bwd_dq",

@@ -19,8 +19,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.collectives._compat import pallas_compiler_params
-
 
 def _rg_lru_kernel(a_ref, b_ref, y_ref, hlast_ref, h_scr, *, block_t: int):
     ti = pl.program_id(2)
@@ -78,7 +76,7 @@ def rg_lru_fwd(a, b, *, block_t: int = 256, block_d: int = 256,
             jax.ShapeDtypeStruct((B, Dp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rg_lru_scan",

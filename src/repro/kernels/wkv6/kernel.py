@@ -28,8 +28,6 @@ import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.collectives._compat import pallas_compiler_params
-
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, slast_ref, s_scr,
                  *, block_t: int):
@@ -119,7 +117,7 @@ def wkv6_fwd(r, k, v, log_w, u, *, block_t: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((B * H, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="wkv6_chunked",

@@ -35,7 +35,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro import configs  # noqa: E402
-from repro.collectives._compat import cost_analysis_dict  # noqa: E402
 from repro.data.pipeline import make_batch_specs  # noqa: E402
 from repro.models import (SHAPES, decode_step, init_caches, init_params,  # noqa: E402
                           loss_fn, prefill)
@@ -217,7 +216,7 @@ def _lower_cell(cfg, shape, mesh, variant: str = "baseline"):
 
 
 def _cell_metrics(compiled) -> dict:
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     return {"flops": cost.get("flops") or 0.0,
             "bytes_accessed": cost.get("bytes accessed") or 0.0,
@@ -262,7 +261,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     try:
         mem = compiled.memory_analysis()
         mem_info = {

@@ -15,11 +15,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    try:  # AxisType landed after jax 0.4.x; the default axis type is fine
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except AttributeError:
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,10 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.collectives._compat import axis_size as _axis_size
-from repro.collectives._compat import pcast as _pcast
-from repro.collectives._compat import shard_map as _shard_map
-
 
 def _shift_perm(n: int, offset: int) -> list[tuple[int, int]]:
     return [(i, (i + offset) % n) for i in range(n)]
@@ -34,7 +30,7 @@ def pipeline_apply(stage_fn, stage_params, x_micro, axis_name: str):
     Returns (M, mb, ...) final-stage outputs (valid on the last stage; other
     stages return zeros), suitable for psum/gather by the caller.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = x_micro.shape[0]
     mb_shape = x_micro.shape[1:]
@@ -44,8 +40,8 @@ def pipeline_apply(stage_fn, stage_params, x_micro, axis_name: str):
     carry = jnp.zeros(mb_shape, x_micro.dtype)
     # mark the loop state as device-varying over the pipeline axis (the loop
     # body mixes in axis_index / ppermute results, which are varying)
-    out = _pcast(out, (axis_name,), to="varying")
-    carry = _pcast(carry, (axis_name,), to="varying")
+    out = jax.lax.pcast(out, (axis_name,), to="varying")
+    carry = jax.lax.pcast(carry, (axis_name,), to="varying")
 
     def tick(t, state):
         out, carry = state
@@ -87,7 +83,7 @@ def run_pipeline(mesh, axis_name, stage_fn, all_stage_params, x, n_micro):
         # broadcast final-stage outputs to every stage for uniform return
         return jax.lax.psum(out, axis_name)
 
-    out = _shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),

@@ -24,7 +24,6 @@ from repro import configs
 from repro.checkpoint import latest_step, restore_into, save
 from repro.collectives import (bruck_all_reduce, compressed_all_reduce,
                                gradient_sync_plan, make_error_feedback_state)
-from repro.collectives._compat import shard_map as _shard_map
 from repro.data import SyntheticLM
 from repro.models import init_params, loss_fn
 from repro.models.sharding import activation_sharding
@@ -111,7 +110,7 @@ def make_train_step(cfg, tc: TrainConfig, mesh):
         pspec_batch = jax.tree.map(lambda _: P(axis), batch)
         # check_vma=False: outputs *are* replicated (explicit Bruck
         # all-reduce), but the ppermute chain defeats static inference.
-        loss, metrics, grads, ef = _shard_map(
+        loss, metrics, grads, ef = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(), pspec_batch, P()),
             out_specs=(P(), P(), P(), P()),

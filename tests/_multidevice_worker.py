@@ -26,9 +26,8 @@ from repro.collectives import (bridge_all_reduce, bruck_all_gather,  # noqa: E40
                                bruck_reduce_scatter, compressed_all_reduce,
                                make_error_feedback_state, ring_all_gather,
                                ring_all_reduce, ring_reduce_scatter)
-from repro.collectives._compat import shard_map  # noqa: E402
 from repro.core import PAPER_DEFAULT, plan  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402  (AxisType compat inside)
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 assert jax.device_count() == N, jax.device_count()
 mesh = make_mesh((N,), ("ring",))
@@ -36,7 +35,7 @@ AXIS = "ring"
 
 
 def smap(f, in_specs, out_specs):
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
 
 def check(name, got, want, atol=1e-5):

@@ -301,11 +301,18 @@ def test_core_imports_and_degrades_without_jax(tmp_path):
         "else:",
         "    raise AssertionError('backend=jax should raise without jax')",
         "try:",
-        "    _compat.shard_map(lambda x: x)",
+        "    _compat.require_jax('a collective')",
+        "except ImportError as e:",
+        "    assert 'a collective' in str(e)",
+        "else:",
+        "    raise AssertionError('require_jax should raise without jax')",
+        "import repro.collectives",
+        "try:",
+        "    repro.collectives.bruck_all_to_all",
         "except ImportError:",
         "    pass",
         "else:",
-        "    raise AssertionError('shard_map should raise without jax')",
+        "    raise AssertionError('collectives should raise without jax')",
         "print('ok')",
     ])
     env = dict(os.environ)
