@@ -77,6 +77,7 @@ import numpy as np
 from .bruck import step_counts
 from .cost_model import CostModel
 from .schedules import Schedule
+from .spans import span
 
 if TYPE_CHECKING:  # faults imports us; only the annotation needs the type
     from .faults import FaultTimeline
@@ -570,30 +571,34 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
     lanes = tuple(lanes)
     if not lanes:
         raise ValueError("batch_run needs at least one lane")
-    tapes = [compile_tape(lane.schedule) for lane in lanes]
-    n, S = tapes[0].n, tapes[0].S
-    for lane, tape in zip(lanes, tapes, strict=True):
-        if tape.n != n or tape.S != S:
-            raise ValueError(
-                f"all lanes must share (n, S); got ({tape.n}, {tape.S}) for "
-                f"{lane.schedule.kind} vs ({n}, {S})")
-    C = max(1, int(chunks_per_msg))
+    with span("batch.tapes"):
+        tapes = [compile_tape(lane.schedule) for lane in lanes]
+        n, S = tapes[0].n, tapes[0].S
+        for lane, tape in zip(lanes, tapes, strict=True):
+            if tape.n != n or tape.S != S:
+                raise ValueError(
+                    f"all lanes must share (n, S); got ({tape.n}, {tape.S}) "
+                    f"for {lane.schedule.kind} vs ({n}, {S})")
+        C = max(1, int(chunks_per_msg))
 
-    m = np.array([lane.m_bytes for lane in lanes])
-    delta, overlap, delta_eff, speed, scale = _knob_arrays(lanes, cm, n)
+        m = np.array([lane.m_bytes for lane in lanes])
+        delta, overlap, delta_eff, speed, scale = _knob_arrays(lanes, cm, n)
 
-    # --- per-lane tape arrays [B, S] ---------------------------------------
-    counts = np.stack([t.arrays["counts"] for t in tapes])
-    g_step = np.stack([t.arrays["g_step"] for t in tapes])
-    hops = np.stack([t.arrays["hops"] for t in tapes])
-    boundary = np.stack([t.arrays["boundary"] for t in tapes])
-    changed = np.stack([t.arrays["changed_pay"] for t in tapes])
-    nb_step = (m[:, None] * counts) / n   # same float-op order as the scalar loop
+        # --- per-lane tape arrays [B, S] -----------------------------------
+        counts = np.stack([t.arrays["counts"] for t in tapes])
+        g_step = np.stack([t.arrays["g_step"] for t in tapes])
+        hops = np.stack([t.arrays["hops"] for t in tapes])
+        boundary = np.stack([t.arrays["boundary"] for t in tapes])
+        changed = np.stack([t.arrays["changed_pay"] for t in tapes])
+        # same float-op order as the scalar loop
+        nb_step = (m[:, None] * counts) / n
 
     if certify:
         from repro.analysis.certifier import certify_batch  # no cycle: analysis imports core only
 
-        certified = certify_batch(lanes, cm)
+        with span("batch.certify") as sp:
+            certified = certify_batch(lanes, cm)
+            sp.set_metadata(certified=int(certified.sum()))
     else:
         certified = np.zeros(len(lanes), dtype=bool)
 
@@ -616,21 +621,24 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
         node_done[jidx] = nd_j
         step_done[jidx] = sd_j
         if uidx.size:
-            nd_u, sd_u, ok_u, _ = _play(
-                n=n, C=C, cm=cm, nb_step=nb_step[uidx], g_step=g_step[uidx],
-                hops=hops[uidx], boundary=boundary[uidx],
-                changed=changed[uidx], delta_eff=delta_eff[uidx],
-                speed=speed[uidx],
-                scale=scale[uidx] if scale is not None else None,
-                check_order=True)
+            with span("batch.host_play", lanes=int(uidx.size)):
+                nd_u, sd_u, ok_u, _ = _play(
+                    n=n, C=C, cm=cm, nb_step=nb_step[uidx],
+                    g_step=g_step[uidx], hops=hops[uidx],
+                    boundary=boundary[uidx], changed=changed[uidx],
+                    delta_eff=delta_eff[uidx], speed=speed[uidx],
+                    scale=scale[uidx] if scale is not None else None,
+                    check_order=True)
             node_done[uidx] = nd_u
             step_done[uidx] = sd_u
             ok[uidx] = ok_u
     else:
-        node_done, step_done, ok, _ = _play(
-            n=n, C=C, cm=cm, nb_step=nb_step, g_step=g_step, hops=hops,
-            boundary=boundary, changed=changed, delta_eff=delta_eff,
-            speed=speed, scale=scale, check_order=not bool(certified.all()))
+        with span("batch.host_play", lanes=len(lanes)):
+            node_done, step_done, ok, _ = _play(
+                n=n, C=C, cm=cm, nb_step=nb_step, g_step=g_step, hops=hops,
+                boundary=boundary, changed=changed, delta_eff=delta_eff,
+                speed=speed, scale=scale,
+                check_order=not bool(certified.all()))
     ok |= certified  # certified lanes are exact by proof, not by observation
 
     completion = node_done.max(axis=1)

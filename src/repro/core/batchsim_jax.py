@@ -33,13 +33,17 @@ candidate sets and 1.6e-13 over 256 lanes at n=1536 (`chip_smoke.py`),
 inside the 1e-6 the backend promises.  Playback is deterministic
 run-to-run on both (the differential suite and the chip smoke pin it).
 
-Hop bucketing.  ``vmap`` runs every lane through the *longest* lane's
-``while_loop`` trip count, so one 2000-hop static-schedule lane would drag a
-whole batch of ~50-hop lanes through 40x the work.  `play_certified` sorts
-lanes by total hops and splits the batch into a few contiguous buckets, each
-jitted at its own shape — measured ~4x over the unbucketed call on wide
-candidate sets, at the cost of at most `max_buckets` compilations per
-``(n, C)``.
+Hop bucketing.  ``vmap`` runs every lane of a call through each step's
+``while_loop`` as often as the call's longest lane there.  `play_certified`
+sorts lanes by total hops and, from 64 lanes up, splits the batch into at
+most `max_buckets` contiguous buckets of at least `min_bucket_size` lanes,
+each jitted at its own shape.  A planner's candidate set is smaller (10-22
+lanes at n = 1024), so it plays as one bucket and its static-schedule lane
+sets every lane's trip count: at n = 1024 about 0.91 of the chunk-services
+the kernel runs are padding (`chunk_services`, carried by the
+``repro.playback`` span; PERF.md has the chip's reading).  Bucketing or
+ordering a set's lanes by hop count is the change to measure before a
+rewrite of the kernel.
 
 Importing this module never requires jax (`repro.collectives._compat`
 guards the probe); `jax_available()` tells callers whether the backend can
@@ -53,6 +57,7 @@ import numpy as np
 
 from repro.collectives._compat import HAS_JAX, require_jax
 from .cost_model import CostModel
+from .spans import span
 
 # trace_count increments only when XLA traces (= compiles) the kernel for a
 # new shape; calls counts every playback dispatch and lanes the certified
@@ -150,6 +155,21 @@ def _bucket_indices(hops: np.ndarray, max_buckets: int,
     return [idx for idx in np.array_split(order, k) if idx.size]
 
 
+def chunk_services(hops: np.ndarray, n: int, C: int) -> tuple[int, int]:
+    """(needed, run) chunk-services of one bucket's ``[lanes, S]`` hops.
+
+    needed = n * C * sum of every lane's hops: what the lanes' playback has
+    to serve, `BatchFabricResult.chunks_moved` summed over them.  run =
+    n * C * lanes * sum over steps k of max over lanes of ``hops[:, k]``:
+    under ``vmap`` every lane of the bucket goes round step k's while_loop
+    as often as the bucket's longest lane there, so 1 - needed / run of
+    what the kernel serves is padding.
+    """
+    h = np.asarray(hops, dtype=np.int64)
+    return (n * C * int(h.sum()),
+            n * C * h.shape[0] * int(h.max(axis=0, initial=0).sum()))
+
+
 def play_certified(*, n: int, C: int, cm: CostModel, nb_step: np.ndarray,
                    g_step: np.ndarray, hops: np.ndarray, changed: np.ndarray,
                    delta_eff: np.ndarray, max_buckets: int = 4,
@@ -185,9 +205,12 @@ def play_certified(*, n: int, C: int, cm: CostModel, nb_step: np.ndarray,
     # the mode into unrelated jax users in the same process
     with jax.enable_x64(True):
         for idx in _bucket_indices(h, max_buckets, min_bucket_size):
-            nd, sd, pf = play(nb[idx], g[idx], h[idx], ch[idx], de[idx],
-                              cm.alpha_s, cm.alpha_h, cm.beta, n=n, C=C)
-            node_done[idx] = np.asarray(nd)
-            step_done[idx] = np.asarray(sd)
-            port_free[idx] = np.asarray(pf)
+            needed, run = chunk_services(h[idx], n, C)
+            with span("playback", lanes=int(idx.size),
+                      chunk_services=needed, chunk_services_run=run):
+                nd, sd, pf = play(nb[idx], g[idx], h[idx], ch[idx], de[idx],
+                                  cm.alpha_s, cm.alpha_h, cm.beta, n=n, C=C)
+                node_done[idx] = np.asarray(nd)
+                step_done[idx] = np.asarray(sd)
+                port_free[idx] = np.asarray(pf)
     return node_done, step_done, port_free

@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import json
 from typing import NamedTuple, Sequence
 
@@ -56,6 +57,7 @@ from repro.core.schedules import Schedule, changed_links, static_schedule
 from repro.core.simulator import (TimeBreakdown, allreduce_time,
                                   allreduce_time_overlap, collective_time,
                                   collective_time_overlap)
+from repro.core.spans import span
 
 from .api import (Candidate, FabricKind, PlanRequest, PlanResult,
                   RankedAlternative)
@@ -120,6 +122,7 @@ class Planner:
             collections.OrderedDict()
         self._hits = 0
         self._misses = 0
+        self._requests = itertools.count()   # `req` of the repro.plan spans
 
     # --- cached serving path -------------------------------------------------
 
@@ -144,28 +147,35 @@ class Planner:
         self._misses = 0
 
     def plan(self, req: PlanRequest) -> PlanResult:
-        if self.cache_size == 0:
-            return self._verified(self._plan_uncached(req))
-        key = self.cache_key(req)
-        hit = self._cache.get(key)
-        if hit is not None:
-            self._hits += 1
-            self._cache.move_to_end(key)
-            return hit
-        self._misses += 1
-        # verify-before-cache: a result that fails static verification must
-        # never be cached, or every later hit would serve the corruption
-        res = self._verified(self._plan_uncached(req))
-        self._cache[key] = res
-        while len(self._cache) > self.cache_size:
-            self._cache.popitem(last=False)
-        return res
+        with span("plan", req=next(self._requests), kind=req.kind) as sp:
+            if self.cache_size == 0:
+                sp.set_metadata(hit=0)
+                return self._verified(self._plan_uncached(req))
+            key = self.cache_key(req)
+            hit = self._cache.get(key)
+            sp.set_metadata(hit=int(hit is not None))
+            if hit is not None:
+                self._hits += 1
+                self._cache.move_to_end(key)
+                return hit
+            self._misses += 1
+            # verify-before-cache: a result that fails static verification
+            # must never be cached, or every later hit would serve the
+            # corruption
+            res = self._verified(self._plan_uncached(req))
+            self._cache[key] = res
+            while len(self._cache) > self.cache_size:
+                self._cache.popitem(last=False)
+            return res
 
     def _verified(self, res: PlanResult) -> PlanResult:
         if self.verify:
-            raise_on_violations(
-                verify_plan(res),
-                context=f"plan({res.request.kind}, n={res.request.n})")
+            with span("plan.verify") as sp:
+                violations = verify_plan(res)
+                sp.set_metadata(violations=len(violations))
+                raise_on_violations(
+                    violations,
+                    context=f"plan({res.request.kind}, n={res.request.n})")
         return res
 
     def plan_batch(self, requests: Sequence[PlanRequest]) -> tuple[PlanResult, ...]:
@@ -227,24 +237,27 @@ class Planner:
         idx = [i for i, c in enumerate(cands) if c.schedule is not None]
         if not idx:
             return {}
-        completions = batch_completion_times(
-            [cands[i].schedule for i in idx], req.m_bytes, req.cost_model,
-            overlap=req.overlap, chunks_per_msg=self.sim_chunks,
-            backend=self.sim_backend)
+        with span("plan.score", lanes=len(idx)):
+            completions = batch_completion_times(
+                [cands[i].schedule for i in idx], req.m_bytes,
+                req.cost_model, overlap=req.overlap,
+                chunks_per_msg=self.sim_chunks, backend=self.sim_backend)
         return {i: float(t) for i, t in zip(idx, completions, strict=True)}
 
     def _plan_collective(self, req: PlanRequest) -> PlanResult:
         cands: list[Candidate] = []
         seen_x: set[tuple[int, ...]] = set()
-        for cand in self._candidates(req, req.kind):
-            # families overlap at the endpoints (static == periodic(R=0),
-            # every-step == periodic(R=S-1)); evaluate each schedule once,
-            # first-registered family keeps the name
-            if cand.schedule is not None:
-                if cand.schedule.x in seen_x:
-                    continue
-                seen_x.add(cand.schedule.x)
-            cands.append(cand)
+        with span("plan.candidates") as sp:
+            for cand in self._candidates(req, req.kind):
+                # families overlap at the endpoints (static == periodic(R=0),
+                # every-step == periodic(R=S-1)); evaluate each schedule
+                # once, first-registered family keeps the name
+                if cand.schedule is not None:
+                    if cand.schedule.x in seen_x:
+                        continue
+                    seen_x.add(cand.schedule.x)
+                cands.append(cand)
+            sp.set_metadata(cands=len(cands))
         if not cands:
             raise ValueError(
                 f"no strategy produced a candidate for {req.kind} "
@@ -254,24 +267,26 @@ class Planner:
 
         best: tuple[float, Candidate, TimeBreakdown, float] | None = None
         ranked: list[RankedAlternative] = []
-        for i, cand in enumerate(cands):
-            bd = self._evaluate(req, req.kind, cand)
-            entry = self._entry_cost(req, cand.schedule)
-            if i in sim_scores:
-                score = predicted = sim_scores[i] + entry
-            else:
-                score = _objective_score(bd, req.objective) + entry
-                predicted = bd.total + entry
-            sched = cand.schedule
-            ranked.append(RankedAlternative(
-                strategy=cand.name, impl=cand.impl, predicted_time=predicted,
-                score=score, R=sched.R if sched is not None else None,
-                x=sched.x if sched is not None else None))
-            if best is None or score < best[0]:
-                best = (score, cand, bd, predicted)
-        assert best is not None
-        _, cand, bd, predicted = best
-        ranked.sort(key=lambda a: a.score)
+        with span("plan.rank"):
+            for i, cand in enumerate(cands):
+                bd = self._evaluate(req, req.kind, cand)
+                entry = self._entry_cost(req, cand.schedule)
+                if i in sim_scores:
+                    score = predicted = sim_scores[i] + entry
+                else:
+                    score = _objective_score(bd, req.objective) + entry
+                    predicted = bd.total + entry
+                sched = cand.schedule
+                ranked.append(RankedAlternative(
+                    strategy=cand.name, impl=cand.impl,
+                    predicted_time=predicted, score=score,
+                    R=sched.R if sched is not None else None,
+                    x=sched.x if sched is not None else None))
+                if best is None or score < best[0]:
+                    best = (score, cand, bd, predicted)
+            assert best is not None
+            _, cand, bd, predicted = best
+            ranked.sort(key=lambda a: a.score)
         return PlanResult(
             request=req, strategy=cand.name, impl=cand.impl,
             predicted_time=predicted, breakdown=bd, schedule=cand.schedule,
@@ -333,10 +348,12 @@ class Planner:
         for k in range(total_cap + 1):
             rs_res = sub("rs", k)
             ag_res = sub("ag", total_cap - k)
-            bd = self._allreduce_bd(req, rs_res.schedule, ag_res.schedule)
-            score = self._allreduce_score(req, rs_res, ag_res, bd)
-            if best is None or score < best[0]:
-                best = (score, rs_res, ag_res)
+            with span("plan.rank"):
+                bd = self._allreduce_bd(req, rs_res.schedule,
+                                        ag_res.schedule)
+                score = self._allreduce_score(req, rs_res, ag_res, bd)
+                if best is None or score < best[0]:
+                    best = (score, rs_res, ag_res)
         assert best is not None
         return best[1], best[2]
 
@@ -362,32 +379,36 @@ class Planner:
                 ag_sched = static_schedule("ag", req.n, req.r)
                 name = "bruck[static]"
             assert rs_sched is not None and ag_sched is not None
-            bd = self._allreduce_bd(req, rs_sched, ag_sched)
-            entry = self._entry_cost(req, rs_sched)
-            if req.fabric == FabricKind.OCS_SIM:
-                score = predicted = (
-                    self._allreduce_score(req, rs_res, ag_res, bd) + entry)
-            else:
-                score = _objective_score(bd, req.objective) + entry
-                predicted = bd.total + entry
-            evaluated.append((name, "bruck", score, predicted, bd,
-                              rs_sched, ag_sched))
-        if want_ring:
-            bd = baselines.ring("ar", req.n, req.m_bytes, req.cost_model)
-            evaluated.append(("ring", "ring",
-                              _objective_score(bd, req.objective), bd.total,
-                              bd, None, None))
-        if not evaluated:
-            raise ValueError(
-                f"no strategy produced an AllReduce candidate "
-                f"(strategies={req.strategies})")
+        with span("plan.rank"):
+            if want_bruck:
+                bd = self._allreduce_bd(req, rs_sched, ag_sched)
+                entry = self._entry_cost(req, rs_sched)
+                if req.fabric == FabricKind.OCS_SIM:
+                    score = predicted = (
+                        self._allreduce_score(req, rs_res, ag_res, bd)
+                        + entry)
+                else:
+                    score = _objective_score(bd, req.objective) + entry
+                    predicted = bd.total + entry
+                evaluated.append((name, "bruck", score, predicted, bd,
+                                  rs_sched, ag_sched))
+            if want_ring:
+                bd = baselines.ring("ar", req.n, req.m_bytes, req.cost_model)
+                evaluated.append(("ring", "ring",
+                                  _objective_score(bd, req.objective),
+                                  bd.total, bd, None, None))
+            if not evaluated:
+                raise ValueError(
+                    f"no strategy produced an AllReduce candidate "
+                    f"(strategies={req.strategies})")
 
-        evaluated.sort(key=lambda e: e[2])
-        name, impl, _, predicted, bd, rs_sched, ag_sched = evaluated[0]
-        ranked = tuple(
-            RankedAlternative(strategy=nm, impl=im, predicted_time=pt,
-                              score=sc, R=(rs.R + ag.R) if rs and ag else None)
-            for nm, im, sc, pt, b, rs, ag in evaluated)
+            evaluated.sort(key=lambda e: e[2])
+            name, impl, _, predicted, bd, rs_sched, ag_sched = evaluated[0]
+            ranked = tuple(
+                RankedAlternative(strategy=nm, impl=im, predicted_time=pt,
+                                  score=sc,
+                                  R=(rs.R + ag.R) if rs and ag else None)
+                for nm, im, sc, pt, b, rs, ag in evaluated)
         return PlanResult(
             request=req, strategy=name, impl=impl, predicted_time=predicted,
             breakdown=bd, rs_schedule=rs_sched, ag_schedule=ag_sched,
