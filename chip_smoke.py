@@ -165,7 +165,9 @@ def plan_phase(n: int = PLAN_N, m_bytes: float = PLAN_M_BYTES,
             f"plan {kind}: n={n} m_bytes={m_bytes} tech={PLAN_TECH} "
             f"alternatives={len(want.alternatives)} "
             f"certified_lanes_on_device={(mid['lanes'] - before['lanes']) // 2} "
-            f"kernel_calls={calls // 2} winner={cold.strategy} "
+            f"playback_calls={calls // 2} "
+            f"kernel_calls={(mid['buckets'] - before['buckets']) // 2} "
+            f"winner={cold.strategy} "
             f"numpy_winner={want.strategy} worst_rel_diff={worst!r} "
             f"cold_s={t1 - t0!r} warm_s={t2 - t1!r} {NOT_A_BENCHMARK}")
 

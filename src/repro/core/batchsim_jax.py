@@ -6,7 +6,7 @@ thousands the per-hop Python dispatch and the guards' bookkeeping dominate;
 this module lowers the *certified* subset of that playback to XLA:
 
   - the `ScheduleTape` stacks (``counts``/``g_step``/``hops``/``changed``)
-    become device arrays with static shapes per ``(n, C)`` bucket,
+    become device arrays with static shapes per ``(n, C)`` and hop bucket,
   - the per-lane step loop becomes a `lax.scan` over S steps (carry: the
     per-port busy-until vector ``F`` and last-receive vector ``recv``),
   - the hop streams become a `lax.while_loop`, chunks an inner `lax.scan`,
@@ -33,17 +33,22 @@ candidate sets and 1.6e-13 over 256 lanes at n=1536 (`chip_smoke.py`),
 inside the 1e-6 the backend promises.  Playback is deterministic
 run-to-run on both (the differential suite and the chip smoke pin it).
 
-Hop bucketing.  ``vmap`` runs every lane of a call through each step's
-``while_loop`` as often as the call's longest lane there.  `play_certified`
-sorts lanes by total hops and, from 64 lanes up, splits the batch into at
-most `max_buckets` contiguous buckets of at least `min_bucket_size` lanes,
-each jitted at its own shape.  A planner's candidate set is smaller (10-22
-lanes at n = 1024), so it plays as one bucket and its static-schedule lane
-sets every lane's trip count: at n = 1024 about 0.91 of the chunk-services
-the kernel runs are padding (`chunk_services`, carried by the
-``repro.playback`` span; PERF.md has the chip's reading).  Bucketing or
-ordering a set's lanes by hop count is the change to measure before a
-rewrite of the kernel.
+Hop buckets.  ``vmap`` runs every lane of a call through each step's
+``while_loop`` as often as the call's longest lane there, so a lane played
+beside a longer one pads.  `play_certified` sorts the lanes by total hops
+and `partition` cuts that order into the contiguous buckets whose predicted
+device time (`_CALL_S`, `_TRIP_S`, `_CHUNK_SERVICE_S`: a per-call, a
+per-trip and a per-chunk-service cost, fitted on a TPU v5e) sums to the
+least.  At n = 1024 every planner candidate set holds one static-schedule
+lane of 1023 hops and lanes of 10-134; the partition plays the static lane
+alone and the rest in a few buckets of like hop counts, which took the
+share of padding in the chunk-services the kernel runs from 0.91 to 0.05
+(PERF.md).  Each bucket's lane count is padded up a fixed ladder (1, 2, 4,
+8, then multiples of 8, `padded_lanes`) with inert lanes, so the compiled
+shapes are few and do not depend on the request; the partition is charged
+for the padding.  Every bucket is dispatched, the one of the most hops
+first so the host dispatches the rest while the device plays it, before
+any is fetched.
 
 Importing this module never requires jax (`repro.collectives._compat`
 guards the probe); `jax_available()` tells callers whether the backend can
@@ -60,10 +65,11 @@ from .cost_model import CostModel
 from .spans import span
 
 # trace_count increments only when XLA traces (= compiles) the kernel for a
-# new shape; calls counts every playback dispatch and lanes the certified
-# lanes those dispatches played.  The jit-cache test pins trace_count flat
-# across repeated same-shape batches.
-_STATS = {"trace_count": 0, "calls": 0, "lanes": 0}
+# new shape; calls counts `play_certified` calls, buckets the jitted `play`
+# dispatches they made, and lanes the certified lanes they played (padding
+# lanes not counted).  The jit-cache test pins trace_count flat across
+# repeated same-shape batches.
+_STATS = {"trace_count": 0, "calls": 0, "buckets": 0, "lanes": 0}
 
 
 def jax_available() -> bool:
@@ -72,9 +78,9 @@ def jax_available() -> bool:
 
 
 def compile_stats() -> dict:
-    """Snapshot of {'trace_count', 'calls', 'lanes'} — kernel
-    (re)compilations, playback dispatches and lanes played since import /
-    `reset_compile_stats`."""
+    """Snapshot of {'trace_count', 'calls', 'buckets', 'lanes'} — kernel
+    (re)compilations, playback calls, the kernel dispatches they made and
+    the certified lanes played since import / `reset_compile_stats`."""
     return dict(_STATS)
 
 
@@ -142,38 +148,109 @@ def _kernel():
     return play
 
 
-def _bucket_indices(hops: np.ndarray, max_buckets: int,
-                    min_bucket_size: int) -> list[np.ndarray]:
-    """Contiguous lane buckets of ascending total hop count.
+# Time one jitted `play` call adds to a playback, as the partition predicts it:
+#   _CALL_S + _TRIP_S * trips + _CHUNK_SERVICE_S * n * C * lanes_run * trips
+# where trips = sum over steps k of the bucket's longest hops[:, k] (the
+# while_loop trips every lane of the call goes round) and lanes_run is the
+# bucket's padded lane count.  Fitted on a TPU v5e ("TPU v5 lite") from a
+# traced run of the n = 1024 planner's candidate sets: the device time of
+# module jit_play over 37 bucket shapes, L_pad 1-8 and 11-1023 trips (0.128
+# ms + 3.55 us a trip + 1.2546 ns a chunk-service run, within 11% of every
+# shape and 2% of the median one), plus the 0.125 ms of host time each
+# further bucket adds to the `repro.playback` span.  Properties of the
+# device, like `batchsim._JAX_AUTO_MIN_WORK`, not user settings; PERF.md
+# has the fit.
+_CALL_S = 0.25e-3
+_TRIP_S = 3.55e-6
+_CHUNK_SERVICE_S = 1.2546e-9
 
-    The stable sort keeps equal-work lanes in input order; small batches stay
-    in one bucket (a bucket per handful of lanes would just multiply compile
-    cost without shortening anyone's while_loop).
+# padded lane counts up to 8 (index = lanes); above 8, the next multiple of 8
+_SMALL_LADDER = np.array([0, 1, 2, 4, 4, 8, 8, 8, 8])
+
+
+def padded_lanes(lanes):
+    """The lane count a bucket of ``lanes`` is compiled and played at: the
+    next rung of the ladder 1, 2, 4, 8, 16, 24, 32, ... (scalar or array)."""
+    lanes = np.asarray(lanes)
+    return np.where(lanes > 8, -(-lanes // 8) * 8,
+                    _SMALL_LADDER[np.minimum(lanes, 8)])
+
+
+def _predicted_seconds(lanes_run, trips, n: int, C: int):
+    """Predicted device time of one `play` call (the model above)."""
+    return (_CALL_S + _TRIP_S * np.asarray(trips)
+            + _CHUNK_SERVICE_S * n * C * np.asarray(lanes_run) * trips)
+
+
+def partition(hops: np.ndarray, n: int, C: int) -> list[np.ndarray]:
+    """Hop buckets of a ``[lanes, S]`` hop matrix: lane indices, one array a
+    `play` call.
+
+    Lanes are sorted by total hops (stable, so equal-work lanes keep input
+    order) and cut into the contiguous buckets whose predicted device time,
+    summed, is least: a shortest path over the cut points, which lie only
+    between runs of equal total hops (O(G^2 * S) for G distinct totals).
+    Each bucket is charged its padded lane count, since padding lanes run
+    every trip under ``vmap`` as well.
     """
-    order = np.argsort(hops.sum(axis=1), kind="stable")
-    k = max(1, min(int(max_buckets), len(order) // max(1, int(min_bucket_size))))
-    return [idx for idx in np.array_split(order, k) if idx.size]
+    total = hops.sum(axis=1)
+    order = np.argsort(total, kind="stable")
+    cuts = np.flatnonzero(np.diff(total[order])) + 1
+    bounds = np.concatenate([[0], cuts, [order.size]])
+    # per-step maxima of each run of equal totals, [G, S]
+    run_max = np.maximum.reduceat(hops[order], bounds[:-1], axis=0)
+    G = run_max.shape[0]
+    best = np.full(G + 1, np.inf)
+    best[0] = 0.0
+    prev = np.zeros(G + 1, dtype=np.int64)
+    for i in range(G):
+        # buckets [bounds[i], bounds[j]) for every j > i
+        trips = np.maximum.accumulate(run_max[i:], axis=0).sum(axis=1)
+        cost = best[i] + _predicted_seconds(
+            padded_lanes(bounds[i + 1:] - bounds[i]), trips, n, C)
+        better = cost < best[i + 1:]
+        best[i + 1:][better] = cost[better]
+        prev[i + 1:][better] = i
+    buckets = []
+    j = G
+    while j:
+        i = prev[j]
+        buckets.append(order[bounds[i]:bounds[j]])
+        j = i
+    return buckets[::-1]
 
 
-def chunk_services(hops: np.ndarray, n: int, C: int) -> tuple[int, int]:
+def chunk_services(hops: np.ndarray, n: int, C: int,
+                   lanes_run: int | None = None) -> tuple[int, int]:
     """(needed, run) chunk-services of one bucket's ``[lanes, S]`` hops.
 
     needed = n * C * sum of every lane's hops: what the lanes' playback has
     to serve, `BatchFabricResult.chunks_moved` summed over them.  run =
-    n * C * lanes * sum over steps k of max over lanes of ``hops[:, k]``:
-    under ``vmap`` every lane of the bucket goes round step k's while_loop
-    as often as the bucket's longest lane there, so 1 - needed / run of
-    what the kernel serves is padding.
+    n * C * lanes_run * sum over steps k of max over lanes of ``hops[:, k]``:
+    under ``vmap`` every lane of the bucket, padding lanes included
+    (``lanes_run``, default the bucket's own lanes), goes round step k's
+    while_loop as often as the bucket's longest lane there, so 1 - needed /
+    run of what the kernel serves is padding.
     """
     h = np.asarray(hops, dtype=np.int64)
+    if lanes_run is None:
+        lanes_run = h.shape[0]
     return (n * C * int(h.sum()),
-            n * C * h.shape[0] * int(h.max(axis=0, initial=0).sum()))
+            n * C * int(lanes_run) * int(h.max(axis=0, initial=0).sum()))
+
+
+def _padded(a: np.ndarray, idx: np.ndarray, lanes_run: int) -> np.ndarray:
+    """Rows ``idx`` of ``a``, then zero rows up to ``lanes_run``: inert
+    padding lanes (no hops, no payload, offset 0) whose results are
+    dropped."""
+    out = np.zeros((lanes_run,) + a.shape[1:], dtype=a.dtype)
+    out[:idx.size] = a[idx]
+    return out
 
 
 def play_certified(*, n: int, C: int, cm: CostModel, nb_step: np.ndarray,
                    g_step: np.ndarray, hops: np.ndarray, changed: np.ndarray,
-                   delta_eff: np.ndarray, max_buckets: int = 4,
-                   min_bucket_size: int = 32):
+                   delta_eff: np.ndarray):
     """Guard-free playback of a certified-lane batch on the XLA backend.
 
     Inputs are the same ``[B, S]`` tape stacks `batchsim.batch_run` builds
@@ -183,8 +260,12 @@ def play_certified(*, n: int, C: int, cm: CostModel, nb_step: np.ndarray,
     the caller (`batch_run`) enforces this; uniformity is what licenses
     dropping the per-port speed/scale arrays and the runtime guards.
 
+    The lanes are split into the hop buckets `partition` chooses, each
+    padded up the `padded_lanes` ladder with inert lanes (no hops, no
+    payload); every bucket is dispatched, the one of the most hops first,
+    before any result is fetched.
     Returns ``(node_done [B, n], step_done [B, S], port_free [B, n])`` as
-    NumPy float64 arrays in the original lane order (bucketing is internal).
+    NumPy float64 arrays in the original lane order.
     """
     jax = require_jax("the JAX batch backend (backend='jax')")
 
@@ -199,18 +280,31 @@ def play_certified(*, n: int, C: int, cm: CostModel, nb_step: np.ndarray,
     ch = np.ascontiguousarray(changed, dtype=bool)
     ch[:, 0] = False          # step 0 never charges delta (x[0] == 0)
     de = np.ascontiguousarray(delta_eff, dtype=np.float64)
-    _STATS["calls"] += 1
-    _STATS["lanes"] += B
-    # x64 as a context, not a global flag: float64 playback without leaking
-    # the mode into unrelated jax users in the same process
-    with jax.enable_x64(True):
-        for idx in _bucket_indices(h, max_buckets, min_bucket_size):
-            needed, run = chunk_services(h[idx], n, C)
-            with span("playback", lanes=int(idx.size),
-                      chunk_services=needed, chunk_services_run=run):
-                nd, sd, pf = play(nb[idx], g[idx], h[idx], ch[idx], de[idx],
-                                  cm.alpha_s, cm.alpha_h, cm.beta, n=n, C=C)
-                node_done[idx] = np.asarray(nd)
-                step_done[idx] = np.asarray(sd)
-                port_free[idx] = np.asarray(pf)
+    with span("playback", lanes=B) as sp:
+        buckets = partition(h, n, C)
+        runs = [int(padded_lanes(idx.size)) for idx in buckets]
+        needed = run = 0
+        for idx, lanes_run in zip(buckets, runs):
+            nd_b, rn_b = chunk_services(h[idx], n, C, lanes_run)
+            needed += nd_b
+            run += rn_b
+        sp.set_metadata(buckets=len(buckets), lanes_run=sum(runs),
+                        chunk_services=needed, chunk_services_run=run)
+        _STATS["calls"] += 1
+        _STATS["buckets"] += len(buckets)
+        _STATS["lanes"] += B
+        # x64 as a context, not a global flag: float64 playback without
+        # leaking the mode into unrelated jax users in the same process
+        with jax.enable_x64(True):
+            # the bucket of the most hops first, so the device plays it
+            # while the host dispatches the rest; then one fetch of all
+            pending = [(idx, play(*(_padded(a, idx, lanes_run)
+                                    for a in (nb, g, h, ch, de)),
+                                  cm.alpha_s, cm.alpha_h, cm.beta, n=n, C=C))
+                       for idx, lanes_run in zip(buckets[::-1], runs[::-1])]
+            fetched = jax.device_get([out for _, out in pending])
+        for (idx, _), (nd, sd, pf) in zip(pending, fetched):
+            node_done[idx] = nd[:idx.size]
+            step_done[idx] = sd[:idx.size]
+            port_free[idx] = pf[:idx.size]
     return node_done, step_done, port_free

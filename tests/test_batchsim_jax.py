@@ -28,7 +28,7 @@ import pytest
 
 from repro.core import PAPER_DEFAULT, periodic_a2a, straggler_speeds
 from repro.core.batchsim import (BatchLane, batch_completion_times,
-                                 batch_run)
+                                 batch_run, compile_tape)
 from repro.core.bruck import schedule_length
 from repro.core.schedules import Schedule
 
@@ -152,6 +152,178 @@ def test_recompilation_count_flat_across_same_shape_batches():
     after = batchsim_jax.compile_stats()
     assert after["trace_count"] == before["trace_count"]
     assert after["calls"] == before["calls"] + 3
+
+
+# --- hop buckets: the cost-chosen partition and the shape ladder ---------------
+
+
+def static_set_hops(lanes: int, static_at: int, seed: int) -> np.ndarray:
+    """Hops shaped like a planner's n = 1024 candidate set: one static lane
+    of 2^k hops at step k (1023 in all), the rest 10..134 in all."""
+    rng = np.random.default_rng(seed)
+    S = 10
+    others = []
+    while len(others) < lanes - 1:
+        row = rng.integers(0, 15, size=S)
+        row[0] = 1
+        if 10 <= row.sum() <= 134:
+            others.append(row)
+    others.insert(static_at, 1 << np.arange(S))
+    return np.array(others, dtype=np.int64)
+
+
+def bucket_cost(hops, buckets, n, C):
+    return sum(float(batchsim_jax._predicted_seconds(
+        batchsim_jax.padded_lanes(b.size), hops[b].max(axis=0).sum(), n, C))
+        for b in buckets)
+
+
+@pytest.mark.parametrize("lanes,static_at", [
+    (10, 9), (13, 0), (17, 8), (22, 21), (22, 3)])
+def test_partition_plays_the_static_lane_alone_unpadded(lanes, static_at):
+    hops = static_set_hops(lanes, static_at, seed=lanes)
+    buckets = batchsim_jax.partition(hops, n=1024, C=8)
+    assert sorted(np.concatenate(buckets).tolist()) == list(range(lanes))
+    alone = [b for b in buckets if static_at in b]
+    assert [b.tolist() for b in alone] == [[static_at]]
+    assert batchsim_jax.padded_lanes(1) == 1
+    assert len(buckets) >= 2
+    # and it beats the one bucket the set played as before
+    assert bucket_cost(hops, buckets, 1024, 8) < 0.5 * bucket_cost(
+        hops, [np.arange(lanes)], 1024, 8)
+
+
+@pytest.mark.parametrize("lanes,seed", [(3, 0), (5, 1), (7, 2), (8, 3)])
+def test_partition_is_the_cheapest_contiguous_split(lanes, seed):
+    """Against every contiguous split of the lanes sorted by total hops
+    (totals distinct, so every cut point is open to the search)."""
+    import itertools
+
+    rng = np.random.default_rng(seed)
+    while True:
+        hops = rng.integers(0, 60, size=(lanes, 6)).astype(np.int64)
+        hops[seed % lanes] = 1 << np.arange(6) * 2
+        if len(set(hops.sum(axis=1).tolist())) == lanes:
+            break
+    n, C = 1024, 8
+    order = np.argsort(hops.sum(axis=1), kind="stable")
+    best = min(
+        bucket_cost(hops, np.split(order, list(cuts)), n, C)
+        for k in range(lanes)
+        for cuts in itertools.combinations(range(1, lanes), k))
+    chosen = batchsim_jax.partition(hops, n, C)
+    assert bucket_cost(hops, chosen, n, C) == pytest.approx(best, rel=1e-12)
+    assert all((np.diff(hops.sum(axis=1)[b]) > 0).all() for b in chosen)
+
+
+def test_partition_of_a_wide_batch_beats_four_equal_buckets():
+    """256 lanes over 18 distinct totals: the cut points are the 17 run
+    boundaries, and the split is at least as cheap as the four equal-count
+    buckets that wide batches used to play as."""
+    rng = np.random.default_rng(11)
+    base = rng.integers(1, 30, size=(18, 11)).astype(np.int64)
+    hops = base[rng.integers(0, 18, size=256)]
+    n, C = 1536, 4
+    buckets = batchsim_jax.partition(hops, n, C)
+    assert sorted(np.concatenate(buckets).tolist()) == list(range(256))
+    assert len(buckets) <= len(set(hops.sum(axis=1).tolist()))
+    order = np.argsort(hops.sum(axis=1), kind="stable")
+    for k in (1, 2, 4):
+        assert bucket_cost(hops, buckets, n, C) <= bucket_cost(
+            hops, np.array_split(order, k), n, C)
+
+
+def test_padded_lanes_climb_the_ladder():
+    lanes = np.arange(1, 301)
+    padded = batchsim_jax.padded_lanes(lanes)
+    ladder = [1, 2, 4, 8] + list(range(16, 305, 8))
+    assert set(padded.tolist()) <= set(ladder)
+    assert (padded >= lanes).all()
+    # the lowest rung that holds them
+    assert all(p == min(r for r in ladder if r >= L)
+               for L, p in zip(lanes.tolist(), padded.tolist()))
+
+
+@pytest.fixture
+def split_everything(monkeypatch):
+    """A cost model with no per-call or per-trip cost: small-n batches then
+    split into several padded buckets, as n = 1024 sets do on the chip."""
+    monkeypatch.setattr(batchsim_jax, "_CALL_S", 0.0)
+    monkeypatch.setattr(batchsim_jax, "_TRIP_S", 0.0)
+
+
+def mixed_hop_lanes(n: int) -> list:
+    """Lanes of one kind and n with very different hop counts: every R of
+    the periodic a2a family, each at three sizes."""
+    S = schedule_length("a2a", n, 2)
+    return [BatchLane(schedule=periodic_a2a(n, R), m_bytes=(1 + R + 0.5 * j) * MB)
+            for R in range(S) for j in range(3)]
+
+
+@pytest.mark.parametrize("n", [12, 48])
+def test_padded_buckets_play_bit_identical_in_lane_order(n, split_everything):
+    cm = PAPER_DEFAULT.replace(delta=1e-3)
+    lanes = mixed_hop_lanes(n)
+    random.Random(n).shuffle(lanes)
+    hops = np.stack([compile_tape(lane.schedule).hops for lane in lanes])
+    buckets = batchsim_jax.partition(hops, n, 4)
+    assert len(buckets) >= 2
+    assert any(batchsim_jax.padded_lanes(b.size) > b.size for b in buckets)
+    res_np = batch_run(lanes, cm, chunks_per_msg=4)
+    res_j = batch_run(lanes, cm, chunks_per_msg=4, backend="jax")
+    assert res_j.backend == "jax" and res_j.certified.all()
+    np.testing.assert_array_equal(res_j.node_done, res_np.node_done)
+    np.testing.assert_array_equal(res_j.step_done, res_np.step_done)
+    np.testing.assert_array_equal(res_j.completion, res_np.completion)
+    for b, lane in enumerate(lanes):
+        assert res_j.completion[b] == pytest.approx(
+            scalar_completion(lane, cm, 4), rel=REL_TOL)
+
+
+def test_stats_count_real_lanes_and_kernel_calls(split_everything):
+    n = 48
+    cm = PAPER_DEFAULT.replace(delta=1e-3)
+    lanes = mixed_hop_lanes(n)
+    hops = np.stack([compile_tape(lane.schedule).hops for lane in lanes])
+    buckets = batchsim_jax.partition(hops, n, 4)
+    before = batchsim_jax.compile_stats()
+    batch_run(lanes, cm, chunks_per_msg=4, backend="jax")
+    after = batchsim_jax.compile_stats()
+    assert after["calls"] - before["calls"] == 1
+    assert after["buckets"] - before["buckets"] == len(buckets) >= 2
+    assert after["lanes"] - before["lanes"] == len(lanes)
+    assert sum(int(batchsim_jax.padded_lanes(b.size)) for b in buckets) > \
+        len(lanes)
+
+
+def test_padded_shapes_are_on_the_ladder_and_compile_once(monkeypatch,
+                                                          split_everything):
+    n = 48
+    cm = PAPER_DEFAULT.replace(delta=1e-3)
+    real = batchsim_jax._kernel()
+    shapes = []
+
+    def recording(nb, *args, **kwargs):
+        shapes.append(nb.shape)
+        return real(nb, *args, **kwargs)
+
+    monkeypatch.setattr(batchsim_jax, "_kernel", lambda: recording)
+    ladder = {1, 2, 4} | set(range(8, 257, 8))
+
+    def run(scale):
+        lanes = [BatchLane(schedule=lane.schedule, m_bytes=lane.m_bytes * scale)
+                 for lane in mixed_hop_lanes(n)]
+        return batch_run(lanes, cm, chunks_per_msg=4, backend="jax")
+
+    run(1.0)
+    first = list(shapes)
+    assert len(first) >= 2 and {L for L, _ in first} <= ladder
+    before = batchsim_jax.compile_stats()
+    for scale in (1.5, 2.0, 3.0):
+        run(scale)
+    after = batchsim_jax.compile_stats()
+    assert after["trace_count"] == before["trace_count"]
+    assert shapes == first * 4
 
 
 def test_x64_mode_does_not_leak_out_of_playback():

@@ -3,8 +3,8 @@
 A live ``jax.profiler`` profile of small plans on the CPU is read back from
 its ``.xplane.pb``: every span of docs/tracing.md appears, nested as stated,
 with the counts it carries; the playback span's chunk-services equal the
-chunks the engine reports moving; and the padding arithmetic of a vmapped
-bucket is checked by hand.  Spans are inert, and import nothing, in a
+chunks the engine reports moving, summed over the hop buckets of the call;
+and the padding arithmetic of a vmapped bucket is checked by hand.  Spans are inert, and import nothing, in a
 process that has not imported jax.
 """
 import glob
@@ -18,10 +18,11 @@ import numpy as np
 import pytest
 
 from repro.core import PAPER_DEFAULT, batchsim, periodic_a2a
-from repro.core.batchsim import BatchLane, batch_run
+from repro.core.batchsim import BatchLane, batch_run, compile_tape
 
 jax = pytest.importorskip("jax")
 
+from repro.core import batchsim_jax  # noqa: E402
 from repro.core.batchsim_jax import chunk_services  # noqa: E402
 from repro.planner import FabricKind, Planner, PlanRequest  # noqa: E402
 
@@ -171,8 +172,14 @@ def test_playback_chunk_services_equal_the_chunks_moved(planned):
         [int(r.certified.sum()) for r in results]
 
 
-def test_playback_spans_cover_every_bucket():
-    """70 lanes make two hop buckets: one span each, the sums still hold."""
+def test_playback_spans_cover_every_bucket(monkeypatch):
+    """One span per playback call, however many hop buckets it plays: it
+    carries the buckets and their padded lanes, and its run count is the sum
+    of every bucket's, padding lanes included."""
+    # no per-call or per-trip cost: these small lanes split into several
+    # padded buckets, as n = 1024 candidate sets do on the chip
+    monkeypatch.setattr(batchsim_jax, "_CALL_S", 0.0)
+    monkeypatch.setattr(batchsim_jax, "_TRIP_S", 0.0)
     n = 8
     lanes = [BatchLane(schedule=periodic_a2a(n, i % 3), m_bytes=(1 + i) * MB)
              for i in range(70)]
@@ -180,10 +187,18 @@ def test_playback_spans_cover_every_bucket():
     spans = profile_spans(lambda: out.append(
         batch_run(lanes, CM, chunks_per_msg=CHUNKS, backend="jax")))
     (res,) = out
-    plays = [s for s in spans if s["name"] == "repro.playback"]
-    assert [s["args"]["lanes"] for s in plays] == [35, 35]
-    assert sum(s["args"]["chunk_services"] for s in plays) == \
-        res.chunks_moved.sum()
+    hops = np.stack([compile_tape(lane.schedule).hops for lane in lanes])
+    buckets = batchsim_jax.partition(hops, n, CHUNKS)
+    runs = [int(batchsim_jax.padded_lanes(b.size)) for b in buckets]
+    assert len(buckets) >= 2 and sum(runs) > len(lanes)
+    (play,) = [s for s in spans if s["name"] == "repro.playback"]
+    assert play["args"]["lanes"] == 70
+    assert play["args"]["buckets"] == len(buckets)
+    assert play["args"]["lanes_run"] == sum(runs)
+    assert play["args"]["chunk_services"] == res.chunks_moved.sum()
+    assert play["args"]["chunk_services_run"] == sum(
+        chunk_services(hops[b], n, CHUNKS, lr)[1]
+        for b, lr in zip(buckets, runs))
     assert not [s for s in spans if s["name"] == "repro.batch.host_play"]
 
 
@@ -205,6 +220,15 @@ def test_chunk_services_count_the_bucket_padding(hops, needed, run):
     n, C = 5, 3
     assert chunk_services(np.array(hops), n, C) == (n * C * needed,
                                                     n * C * run)
+
+
+@pytest.mark.parametrize("lanes_run,run", [(2, 8), (4, 16), (8, 32)])
+def test_chunk_services_count_the_padding_lanes(lanes_run, run):
+    """A bucket of 2 lanes, 4 trips, padded to ``lanes_run`` lanes: every
+    padding lane runs the 4 trips too."""
+    n, C = 5, 3
+    assert chunk_services(np.array([[1, 1], [3, 1]]), n, C, lanes_run) == (
+        n * C * 6, n * C * run)
 
 
 def test_spans_are_inert_and_import_nothing_without_jax():
