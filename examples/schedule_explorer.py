@@ -193,7 +193,7 @@ def main():
             ts = batch_completion_times(
                 [static_schedule("rs", n, args.radix),
                  static_schedule("ag", n, args.radix)], m, cm,
-                overlap=args.overlap, chunks_per_msg=8)
+                overlap=args.overlap, chunks_per_msg=8, ports=args.ports)
             t_static = float(ts[0] + ts[1])
         else:
             t_static = (baselines.s_bruck("rs", n, m, cm, r=args.radix).total
@@ -206,7 +206,7 @@ def main():
             ts = batch_completion_times(
                 [static_schedule(kind, n, args.radix),
                  every_step_schedule(kind, n, args.radix)], m, cm,
-                overlap=args.overlap, chunks_per_msg=8)
+                overlap=args.overlap, chunks_per_msg=8, ports=args.ports)
             t_sbruck, t_gbruck = float(ts[0]), float(ts[1])
         elif hidden:
             from repro.core import collective_time_overlap, every_step_schedule

@@ -20,8 +20,13 @@ Soundness.  Call a lane *uniform* when it has no per-node skew:
 initial snapshot.  On a uniform lane every port sees bit-identical float
 values at every stage of the playback — the fabric is rotationally
 symmetric, all ports share one ``inj`` / ``F`` / ``tau`` value per step, and
-the gather by a constant link offset permutes equal values.  Under that
-symmetry:
+the gather by a constant link offset permutes equal values.  A blocked lane
+(the Section 3.7 port-limited ring, `core.fabricsim`) gathers by another
+permutation — over the optical circuit on a block's first node, the
+electrical link elsewhere — which permutes equal values all the same, and
+every port still serves one source's C chunks per hop stream; so the
+argument below holds for it word for word, on its tape's hop counts.
+Under that symmetry:
 
   - guard 1 is unreachable or strictly satisfied whenever each step has
     ``hops <= 1`` (no relayed stream exists) or its hop-1 arrival is
@@ -74,7 +79,7 @@ from repro.core.schedules import Schedule
 
 
 @functools.lru_cache(maxsize=8192)
-def _certify_schedule(schedule: Schedule, alpha_s_pos: bool,
+def _certify_schedule(schedule: Schedule, block: int, alpha_s_pos: bool,
                       alpha_h_pos: bool, payload_pos: bool) -> bool:
     """Memoized per-(schedule, regime) certificate for one uniform payload
     phase.  The regime is collapsed to the three booleans the soundness
@@ -84,7 +89,7 @@ def _certify_schedule(schedule: Schedule, alpha_s_pos: bool,
         return False
     if alpha_h_pos or payload_pos:            # guard 1 strictly satisfied
         return True
-    tape = compile_tape(schedule)
+    tape = compile_tape(schedule, block)
     return max(tape.hops, default=0) <= 1     # guard 1 unreachable
 
 
@@ -95,7 +100,7 @@ def certify_lane(lane: BatchLane, cm: CostModel) -> bool:
     if lane.link_speed is not None or lane.payload_scale is not None:
         return False                          # skew breaks port symmetry
     return _certify_schedule(
-        lane.schedule, cm.alpha_s > 0.0, cm.alpha_h > 0.0,
+        lane.schedule, lane.block, cm.alpha_s > 0.0, cm.alpha_h > 0.0,
         lane.m_bytes * cm.beta > 0.0)
 
 
@@ -108,7 +113,7 @@ def certify_trace_lane(lane: TraceLane, cm: CostModel) -> bool:
         return False
     a_s, a_h = cm.alpha_s > 0.0, cm.alpha_h > 0.0
     return all(
-        _certify_schedule(sched, a_s, a_h, m * cm.beta > 0.0)
+        _certify_schedule(sched, 1, a_s, a_h, m * cm.beta > 0.0)
         for sched, m in lane.phases)
 
 

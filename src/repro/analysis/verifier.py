@@ -33,6 +33,7 @@ from typing import TYPE_CHECKING, Sequence
 
 from repro.core.batchsim import FabricSnapshot, ScheduleTape, compile_tape
 from repro.core.schedules import Schedule, changed_links
+from repro.core.subrings import BlockedRing, blocked_circuit, blocked_hops
 
 from .violations import Violation
 
@@ -239,17 +240,31 @@ def verify_tape(tape: ScheduleTape) -> tuple[Violation, ...]:
                 f"circuit set u -> u+{g} is not a conflict-free subring "
                 f"permutation", where=f" step {k}")
 
+    # tape/block: blocks of B nodes (Section 3.7) tile the ring, and each
+    # step's optical stride is the one its link offset gives on them
+    B = tape.block
+    if B < 1 or n % B:
+        bad("tape/block", f"blocks of {B} nodes do not tile n={n}")
+        return tuple(out)
+    stride = [blocked_circuit(g, B) for g in tape.g_step]
+    if list(tape.stride) != stride:
+        bad("tape/block", f"stride {list(tape.stride)} != the optical "
+            f"strides {stride} of link offsets {list(tape.g_step)} on "
+            f"blocks of {B}")
+
     # tape/reach (generalized Lemma 3.2): a step's destination is reachable
     # inside its segment's subring iff the message offset is divisible by
     # the link offset; tape/hops pins the claimed hop counts to offset / g
+    # (x B on blocks the circuit shortens, offset on the static ring)
     for k in range(S):
         g, off = tape.g_step[k], tape.offsets[k]
         if g >= 1 and off % g != 0:
             bad("tape/reach",
                 f"offset {off} is not divisible by link offset {g}: the "
                 f"destination is unreachable in the subring", where=f" step {k}")
-        elif g >= 1 and tape.hops[k] != off // g:
-            bad("tape/hops", f"hops {tape.hops[k]} != offset/g = {off // g}",
+        elif g >= 1 and tape.hops[k] != blocked_hops(off, g, B):
+            bad("tape/hops", f"hops {tape.hops[k]} != "
+                f"{blocked_hops(off, g, B)} (offset {off}, g {g}, block {B})",
                 where=f" step {k}")
     want_seg_hops = [sum(tape.hops[a:b + 1]) for a, b in segments]
     if len(tape.seg_hops) != n_seg or list(tape.seg_hops) != want_seg_hops:
@@ -260,8 +275,7 @@ def verify_tape(tape: ScheduleTape) -> tuple[Violation, ...]:
     # boundaries that physically rewire circuits; changed_links carries the
     # per-reconfiguration changed-circuit count (uniform subrings: 0 or n)
     for k in range(S):
-        want = bool(tape.boundary[k]) and k > 0 and \
-            tape.g_step[k] != tape.g_step[k - 1]
+        want = bool(tape.boundary[k]) and k > 0 and stride[k] != stride[k - 1]
         if bool(tape.changed_pay[k]) != want:
             bad("tape/changed",
                 f"changed_pay {bool(tape.changed_pay[k])} != {want} "
@@ -381,6 +395,9 @@ def verify_plan(res: "PlanResult") -> list[Violation]:
                 schedules = [res.schedule]
     for sched in schedules:
         out.extend(verify_schedule(sched))
+    block = req.block
+    if block > 1 and res.impl == "bruck" and schedules:
+        _check_block(bad, req, res, schedules, block)
 
     # plan/budget: reconfiguration caps hold; static fabrics never rewire
     cap = req.effective_max_R()
@@ -444,6 +461,68 @@ def verify_plan(res: "PlanResult") -> list[Violation]:
             bad("plan/budget", f"alternative {i} ({alt.strategy!r}) spends "
                 f"R={sum(alt.x)} > effective cap {cap}")
     return out
+
+
+def _check_block(bad, req, res, schedules: list[Schedule], block: int) -> None:
+    """plan/block: a port-limited plan is scored on its request's blocks.
+
+    The breakdown's per-step hops are the analytic Section 3.7 floor
+    (`BlockedRing.effective_hops`) of the request's ports; under
+    ``ocs-sim`` no simulated score beats its schedule's per-port service
+    floor on those blocks (`_service_floor`).
+    """
+    ring = BlockedRing(n=req.n, ports=req.ports)
+    want = []
+    for sched in schedules:
+        tape = compile_tape(sched)
+        want += [ring.effective_hops(off, g)
+                 for off, g in zip(tape.offsets, tape.g_step, strict=True)]
+    got = [sc.hops for sc in res.breakdown.steps]
+    if got != want:
+        bad("plan/block", f"breakdown hops {got} != the effective hops "
+            f"{want} on blocks of {block} (ports={req.ports})")
+    if req.fabric != "ocs-sim":
+        return
+    cm, m = req.cost_model, req.m_bytes
+    floors = [(res.strategy, res.predicted_time,
+               sum(_service_floor(s, block, m, cm, req.overlap)
+                   for s in schedules))]
+    if req.kind != "ar":
+        floors += [(a.strategy, a.predicted_time, _service_floor(
+            Schedule(kind=req.kind, n=req.n, x=a.x, r=req.r), block, m, cm,
+            req.overlap)) for a in res.alternatives if a.x is not None]
+    for name, predicted, floor in floors:
+        if predicted < floor * (1.0 - REL_TOL):
+            bad("plan/block",
+                f"{name!r} predicts {predicted!r} s, below the {floor!r} s "
+                f"its ports serve on blocks of {block}: scored on fewer "
+                f"hops than the request's fabric has",
+                repro=f"ports={req.ports} m={m!r}")
+
+
+@functools.lru_cache(maxsize=4096)
+def _block_profile(sched: Schedule, block: int) -> tuple[tuple[int, float, bool], ...]:
+    """(hops, block count / n, the circuits change) of every step of
+    ``sched`` as the event engine plays it on blocks of ``block`` nodes."""
+    tape = compile_tape(sched, block)
+    return tuple((h, cnt / tape.n, k > 0 and changed)
+                 for k, (h, cnt, changed) in enumerate(zip(
+                     tape.hops, tape.counts, tape.changed_pay, strict=True)))
+
+
+def _service_floor(sched: Schedule, block: int, m: float, cm: "CostModel",
+                   overlap: float) -> float:
+    """Least completion an event-scored uniform lane can reach: every port
+    serves each of a step's hop streams, so a step holds its ports for
+    hops x (m x count / n) x beta after max(its injection, the port's last
+    service + the rewiring stall), and delivers alpha_h later."""
+    free = recv = 0.0
+    stall = cm.delta * (1.0 - overlap)
+    for hops, share, rewired in _block_profile(sched, block):
+        start = max(recv + cm.alpha_s, free + (stall if rewired else 0.0))
+        free = start + hops * (m * share) * cm.beta
+        recv = free + cm.alpha_h
+    return recv
 
 
 # --- trace / serving level ----------------------------------------------------

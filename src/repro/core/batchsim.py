@@ -78,6 +78,7 @@ from .bruck import step_counts
 from .cost_model import CostModel
 from .schedules import Schedule
 from .spans import span
+from .subrings import block_size, blocked_circuit, blocked_hops
 
 if TYPE_CHECKING:  # faults imports us; only the annotation needs the type
     from .faults import FaultTimeline
@@ -173,6 +174,17 @@ class ScheduleTape:
     use, so scaling is bit-identical).  Plain tuples keep the tape hashable
     and cheap for the scalar loop; `arrays` caches the NumPy views the batch
     engine indexes with.
+
+    ``block`` is the fabric's block size B (paper Section 3.7; 1 on a
+    full-port OCS).  A chunk's next node is `subrings.blocked_successor` of
+    the port that served it under the step's ``stride`` (the optical hop's
+    stride, `subrings.blocked_circuit`), and ``hops`` counts every hop, the
+    electrical ones too (`subrings.blocked_hops`: the analytic floor
+    `BlockedRing.effective_hops` wherever a circuit of whole blocks
+    realizes the link offset, the static ring's offset elsewhere).  The
+    circuits of a segment are its stride: ``changed_pay`` marks boundaries
+    whose stride changes.  With B = 1 the stride is the link offset and the
+    tape is the full-port one.
     """
 
     kind: str
@@ -189,6 +201,8 @@ class ScheduleTape:
     seg_g: tuple[int, ...]          # link offset per segment
     seg_hops: tuple[int, ...]       # total hops per segment (per-port services / C)
     changed_links: tuple[int, ...]  # Schedule.reconfig_changed_links()
+    block: int = 1                  # nodes per block (Section 3.7; 1 = full-port)
+    stride: tuple[int, ...] = ()    # optical hop stride per sub-step
 
     @functools.cached_property
     def arrays(self) -> dict[str, np.ndarray]:
@@ -196,6 +210,7 @@ class ScheduleTape:
             "offsets": np.array(self.offsets, dtype=np.int64),
             "counts": np.array(self.counts, dtype=np.float64),
             "g_step": np.array(self.g_step, dtype=np.int64),
+            "stride": np.array(self.stride, dtype=np.int64),
             "hops": np.array(self.hops, dtype=np.int64),
             "changed_pay": np.array(self.changed_pay, dtype=bool),
             "boundary": np.array(self.boundary, dtype=bool),
@@ -206,14 +221,21 @@ class ScheduleTape:
 
 
 @functools.lru_cache(maxsize=4096)
-def compile_tape(schedule: Schedule) -> ScheduleTape:
-    """Lower ``schedule`` to its playback tape (memoized per Schedule)."""
+def compile_tape(schedule: Schedule, block: int = 1) -> ScheduleTape:
+    """Lower ``schedule`` to its playback tape on blocks of ``block`` nodes
+    (memoized per (Schedule, block)).  Blocks must tile the ring."""
     kind, n, r = schedule.kind, schedule.n, schedule.r
+    if block < 1 or n % block:
+        raise ValueError(
+            f"blocks of {block} nodes do not tile a ring of n={n}: the "
+            f"blocked-ring event model needs the block size to divide n")
     structure = step_counts(kind, n, r)
     offsets = tuple(off for off, _, _, _ in structure)
     counts = tuple(cnt for _, cnt, _, _ in structure)
     g_step = tuple(schedule.link_offsets())
-    hops = tuple(off // g for off, g in zip(offsets, g_step, strict=True))
+    stride = tuple(blocked_circuit(g, block) for g in g_step)
+    hops = tuple(blocked_hops(off, g, block)
+                 for off, g in zip(offsets, g_step, strict=True))
     segs = schedule.segments
     seg_of = [0] * len(offsets)
     for si, (a, b) in enumerate(segs):
@@ -222,13 +244,14 @@ def compile_tape(schedule: Schedule) -> ScheduleTape:
     seg_g = tuple(g_step[a] for a, _ in segs)
     seg_hops = tuple(sum(hops[a:b + 1]) for a, b in segs)
     changed_pay = tuple(
-        bool(xk) and g_step[k] != g_step[k - 1]
+        bool(xk) and stride[k] != stride[k - 1]
         for k, xk in enumerate(schedule.x))
     return ScheduleTape(
         kind=kind, n=n, r=r, S=len(offsets), offsets=offsets, counts=counts,
         g_step=g_step, hops=hops, boundary=tuple(schedule.x),
         changed_pay=changed_pay, seg_of=tuple(seg_of), seg_g=seg_g,
-        seg_hops=seg_hops, changed_links=schedule.reconfig_changed_links())
+        seg_hops=seg_hops, changed_links=schedule.reconfig_changed_links(),
+        block=block, stride=stride)
 
 
 def clear_tape_caches() -> None:
@@ -247,6 +270,9 @@ class BatchLane:
     overlap        : fraction of delta hidden behind communication, [0, 1].
     link_speed     : per-node relative egress rate (None = nominal).
     payload_scale  : per-destination payload multiplier (None = uniform).
+    ports          : OCS port count; < 2n plays the Section 3.7 blocked
+                     ring (blocks of ceil(2n / ports) nodes, which must
+                     divide n; see `ScheduleTape`).  None = full-port.
     """
 
     schedule: Schedule
@@ -255,6 +281,7 @@ class BatchLane:
     overlap: float = 0.0
     link_speed: tuple[float, ...] | None = None
     payload_scale: tuple[float, ...] | None = None
+    ports: int | None = None
 
     def __post_init__(self):
         if not 0.0 <= self.overlap <= 1.0:
@@ -269,6 +296,17 @@ class BatchLane:
             if v is not None:
                 object.__setattr__(self, name, tuple(validate_rates(name, v, n)))
         object.__setattr__(self, "m_bytes", float(self.m_bytes))
+        if self.ports is not None and self.ports < 1:
+            raise ValueError(f"ports must be >= 1, got {self.ports}")
+        if n % self.block:
+            raise ValueError(
+                f"ports={self.ports} gives blocks of {self.block} nodes, "
+                f"which do not tile n={n}")
+
+    @property
+    def block(self) -> int:
+        """Nodes per block (1 on a full-port fabric)."""
+        return block_size(self.schedule.n, self.ports)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -354,7 +392,7 @@ class BatchFabricResult:
         """Lane i as a scalar-compatible `FabricResult` (mode='batched')."""
         from .fabricsim import FabricResult  # deferred: fabricsim imports us
 
-        tape = compile_tape(self.lanes[i].schedule)
+        tape = compile_tape(self.lanes[i].schedule, self.lanes[i].block)
         return FabricResult(
             completion=float(self.completion[i]), mode="batched",
             step_done=tuple(float(t) for t in self.step_done[i]),
@@ -388,13 +426,19 @@ def _knob_arrays(lanes, cm: CostModel, n: int):
     return delta, overlap, delta_eff, speed, scale
 
 
-def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
-          changed, delta_eff, speed, scale, F0=None, ready0=None,
-          changed0=None, check_order: bool = True):
+def _play(*, n: int, C: int, cm: CostModel, nb_step, offsets, stride, block,
+          hops, boundary, changed, delta_eff, speed, scale, F0=None,
+          ready0=None, changed0=None, check_order: bool = True):
     """Canonical-order tape playback over [B, S] step arrays.
 
     ``nb_step[b, k]`` is lane b's per-node payload of sub-step k (before any
-    destination scaling); ``boundary`` marks steps that open a new segment
+    destination scaling); ``offsets`` / ``stride`` / ``hops`` are the tape's
+    message offsets, optical strides and hop counts, and ``block[b]`` lane
+    b's block size: the chunk port p serves moves on to
+    `subrings.blocked_successor` of p, so port p's next arrivals are
+    gathered from the port whose successor it is, ``p - stride`` on a
+    block's first node and ``p - 1`` elsewhere (every node is a block's
+    first when B = 1).  ``boundary`` marks steps that open a new segment
     (the scalar loop's per-port segment gate resets there) and ``changed``
     marks steps whose opening boundary physically rewires circuits (those
     charge ``delta_eff``).  ``F0`` / ``ready0`` / ``changed0`` resume lanes
@@ -417,6 +461,7 @@ def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
     B, S = nb_step.shape
     alpha_s, alpha_h, beta = cm.alpha_s, cm.alpha_h, cm.beta
     ports = np.arange(n, dtype=np.int64)[None, :]           # [1, n]
+    first = (ports % np.asarray(block, dtype=np.int64)[:, None]) == 0  # [B, n]
 
     # port busy-until / injection times of the current step, warm-started
     # from the snapshot arrays in the same float-op order as the scalar
@@ -436,11 +481,12 @@ def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
             inj = recv + alpha_s
             F = F + np.where(changed[:, k], delta_eff, 0.0)[:, None]
         h = hops[:, k]                                       # [B]
-        g = g_step[:, k]                                     # [B]
         nb = nb_step[:, k]                                   # [B]
-        gather_idx = (ports - g[:, None]) % n                # [B, n]
+        gather_idx = (ports - np.where(first, stride[:, k][:, None], 1)) % n
         gather_idx3 = np.broadcast_to(gather_idx[:, :, None], (B, n, C))
         arr = np.broadcast_to(inj[:, :, None], (B, n, C))    # stream-0 arrivals
+        if scale is not None:
+            src = np.broadcast_to(ports, (B, n))             # source of each chunk
         if check_order:
             first_arr, last_arr = inj.copy(), inj.copy()     # min/max over streams
         recv = np.empty((B, n))
@@ -452,7 +498,7 @@ def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
             if scale is None:
                 nbw = np.broadcast_to(nb[:, None], (B, n))
             else:
-                dest = (ports + ((h - j) * g)[:, None]) % n
+                dest = (src + offsets[:, k][:, None]) % n
                 nbw = nb[:, None] * np.take_along_axis(scale, dest, axis=1)
             tau = (nbw / C) * beta / speed
             f = F
@@ -469,6 +515,8 @@ def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
             cont = active & (j + 1 < h)
             if not cont.any():
                 break
+            if scale is not None:
+                src = np.take_along_axis(src, gather_idx, axis=1)
             if check_order:
                 if j == 0:
                     # a hop-1 chunk overtaking the port's own injection breaks
@@ -496,12 +544,14 @@ def _play(*, n: int, C: int, cm: CostModel, nb_step, g_step, hops, boundary,
     return recv, step_done, ok, F
 
 
-# "auto" switches to the JAX backend only above this estimated certified
-# work, in chunk-services (C * n * total certified hops).  Calibrated on the
+# "auto" on a device kind with no measured playback costs (the CPU among
+# them) switches to the JAX backend only above this estimated certified work,
+# in chunk-services (C * n * total certified hops).  Calibrated on the
 # CI-class single-core CPU (benchmarks/sim_bench.py): below it the NumPy
 # loop's per-hop dispatch is cheaper than jit dispatch + (first-call)
 # compilation; the planner's n=96 candidate sets (~1e6) stay on NumPy, wide
-# n>=768 sets (>=1e7) go to XLA.
+# n>=768 sets (>=1e7) go to XLA.  A kind with measured costs compares the
+# two engines' predicted times instead (`batchsim_jax.prefers_device`).
 _JAX_AUTO_MIN_WORK = 5e6
 
 
@@ -512,8 +562,11 @@ def _resolve_backend(backend: str, *, certify: bool, certified: np.ndarray,
     "jax" demands jax and ``certify=True`` (the XLA kernel is guard-free —
     only certified lanes may enter it) but degrades to "numpy" when no lane
     in *this* batch is certified, since there would be nothing for the
-    kernel to do.  "auto" additionally requires the certified work to clear
-    `_JAX_AUTO_MIN_WORK` so small batches keep NumPy's lower fixed cost.
+    kernel to do.  "auto" plays the certified lanes where they are
+    predicted to finish first: on a device kind with measured costs, the
+    hop-bucket cost model of the device against NumPy's measured cost per
+    chunk-service on that device's host; on any other kind, the device
+    when the certified work clears `_JAX_AUTO_MIN_WORK`.
     """
     if backend not in ("numpy", "jax", "auto"):
         raise ValueError(
@@ -535,13 +588,16 @@ def _resolve_backend(backend: str, *, certify: bool, certified: np.ndarray,
 
             require_jax("backend='jax' batch playback")  # raises ImportError
         return "jax" if bool(certified.any()) else "numpy"
-    # auto: opt in only when jax exists and the certified work amortizes it
-    from .batchsim_jax import jax_available
+    # auto: opt in only when jax exists and the device is predicted faster
+    from .batchsim_jax import jax_available, prefers_device
 
     if not jax_available() or not bool(certified.any()):
         return "numpy"
-    work = float(C) * n * float(hops[certified].sum())
-    return "jax" if work >= _JAX_AUTO_MIN_WORK else "numpy"
+    device = prefers_device(hops[certified], n, C)
+    if device is None:
+        work = float(C) * n * float(hops[certified].sum())
+        device = work >= _JAX_AUTO_MIN_WORK
+    return "jax" if device else "numpy"
 
 
 def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
@@ -572,7 +628,7 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
     if not lanes:
         raise ValueError("batch_run needs at least one lane")
     with span("batch.tapes"):
-        tapes = [compile_tape(lane.schedule) for lane in lanes]
+        tapes = [compile_tape(lane.schedule, lane.block) for lane in lanes]
         n, S = tapes[0].n, tapes[0].S
         for lane, tape in zip(lanes, tapes, strict=True):
             if tape.n != n or tape.S != S:
@@ -586,10 +642,15 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
 
         # --- per-lane tape arrays [B, S] -----------------------------------
         counts = np.stack([t.arrays["counts"] for t in tapes])
+        offsets = np.stack([t.arrays["offsets"] for t in tapes])
         g_step = np.stack([t.arrays["g_step"] for t in tapes])
+        stride = np.stack([t.arrays["stride"] for t in tapes])
         hops = np.stack([t.arrays["hops"] for t in tapes])
         boundary = np.stack([t.arrays["boundary"] for t in tapes])
         changed = np.stack([t.arrays["changed_pay"] for t in tapes])
+        block = np.array([t.block for t in tapes], dtype=np.int64)
+        # hops the block floor adds over a full-port fabric's offset / g
+        added_hops = (hops - offsets // g_step).sum(axis=1)
         # same float-op order as the scalar loop
         nb_step = (m[:, None] * counts) / n
 
@@ -616,15 +677,19 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
         step_done = np.empty((B, S))
         ok = np.ones(B, dtype=bool)
         nd_j, sd_j, _ = play_certified(
-            n=n, C=C, cm=cm, nb_step=nb_step[jidx], g_step=g_step[jidx],
-            hops=hops[jidx], changed=changed[jidx], delta_eff=delta_eff[jidx])
+            n=n, C=C, cm=cm, nb_step=nb_step[jidx], stride=stride[jidx],
+            block=block[jidx], hops=hops[jidx], changed=changed[jidx],
+            delta_eff=delta_eff[jidx],
+            blocked_hops=int(added_hops[jidx].sum()))
         node_done[jidx] = nd_j
         step_done[jidx] = sd_j
         if uidx.size:
-            with span("batch.host_play", lanes=int(uidx.size)):
+            with span("batch.host_play", lanes=int(uidx.size),
+                      blocked_hops=int(added_hops[uidx].sum())):
                 nd_u, sd_u, ok_u, _ = _play(
                     n=n, C=C, cm=cm, nb_step=nb_step[uidx],
-                    g_step=g_step[uidx], hops=hops[uidx],
+                    offsets=offsets[uidx], stride=stride[uidx],
+                    block=block[uidx], hops=hops[uidx],
                     boundary=boundary[uidx], changed=changed[uidx],
                     delta_eff=delta_eff[uidx], speed=speed[uidx],
                     scale=scale[uidx] if scale is not None else None,
@@ -633,9 +698,11 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
             step_done[uidx] = sd_u
             ok[uidx] = ok_u
     else:
-        with span("batch.host_play", lanes=len(lanes)):
+        with span("batch.host_play", lanes=len(lanes),
+                  blocked_hops=int(added_hops.sum())):
             node_done, step_done, ok, _ = _play(
-                n=n, C=C, cm=cm, nb_step=nb_step, g_step=g_step, hops=hops,
+                n=n, C=C, cm=cm, nb_step=nb_step, offsets=offsets,
+                stride=stride, block=block, hops=hops,
                 boundary=boundary, changed=changed, delta_eff=delta_eff,
                 speed=speed, scale=scale,
                 check_order=not bool(certified.all()))
@@ -661,7 +728,8 @@ def batch_run(lanes: Sequence[BatchLane], cm: CostModel, *,
                 link_speed=(list(lane.link_speed)
                             if lane.link_speed is not None else None),
                 payload_scale=(list(lane.payload_scale)
-                               if lane.payload_scale is not None else None))
+                               if lane.payload_scale is not None else None),
+                ports=lane.ports)
             res = sim.run(lane.schedule, float(m[b]),
                           cm.replace(delta=float(delta[b])))
             completion[b] = res.completion
@@ -777,6 +845,8 @@ def batch_run_trace(lanes: Sequence[TraceLane], cm: CostModel, *,
     # --- concatenated per-lane tape arrays [B, S] --------------------------
     g_step = np.stack([np.concatenate([t.arrays["g_step"] for t in ts])
                        for ts in tapes])
+    offsets = np.stack([np.concatenate([t.arrays["offsets"] for t in ts])
+                        for ts in tapes])
     hops = np.stack([np.concatenate([t.arrays["hops"] for t in ts])
                      for ts in tapes])
     boundary = np.stack([np.concatenate([t.arrays["boundary"] for t in ts])
@@ -829,8 +899,10 @@ def batch_run_trace(lanes: Sequence[TraceLane], cm: CostModel, *,
         certified = np.zeros(B, dtype=bool)
     certified &= ~faulted  # a certificate cannot cover a mid-trace fault
 
+    # traces play on a full-port fabric: blocks of one node, stride g
     node_done, step_done, ok, port_free = _play(
-        n=n, C=C, cm=cm, nb_step=nb_step, g_step=g_step, hops=hops,
+        n=n, C=C, cm=cm, nb_step=nb_step, offsets=offsets, stride=g_step,
+        block=np.ones(B, dtype=np.int64), hops=hops,
         boundary=boundary, changed=changed, delta_eff=delta_eff,
         speed=speed, scale=scale, F0=F0, ready0=ready0, changed0=changed0,
         check_order=not bool(certified.all()))
@@ -890,16 +962,17 @@ def batch_run_trace(lanes: Sequence[TraceLane], cm: CostModel, *,
 def batch_completion_times(schedules: Sequence[Schedule], m: float,
                            cm: CostModel, *, overlap: float = 0.0,
                            chunks_per_msg: int = 32,
-                           backend: str = "numpy") -> np.ndarray:
+                           backend: str = "numpy",
+                           ports: int | None = None) -> np.ndarray:
     """Event-level completion time of every schedule in one batched call.
 
     The planner's ``fabric='ocs-sim'`` scoring primitive: all schedules share
     (n, S) — e.g. one request's full candidate set — and the same payload /
-    cost model / overlap credit.  ``backend`` is forwarded to `batch_run`
-    (the planner passes ``"auto"`` so wide large-n candidate sets score on
-    the JAX engine when it is available).
+    cost model / overlap credit / port count.  ``backend`` is forwarded to
+    `batch_run` (the planner passes ``"auto"`` so candidate sets score on
+    the JAX engine where it is predicted faster).
     """
-    lanes = [BatchLane(schedule=s, m_bytes=m, overlap=overlap)
+    lanes = [BatchLane(schedule=s, m_bytes=m, overlap=overlap, ports=ports)
              for s in schedules]
     return batch_run(lanes, cm, chunks_per_msg=chunks_per_msg,
                      backend=backend).completion

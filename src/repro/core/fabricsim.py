@@ -23,7 +23,55 @@ the surviving subring links keep carrying traffic — from a full-fabric one.
   - a node begins sub-step k+1 transmissions as soon as its *own* sub-step-k
     receive completed (per-node dependency tracking; no global barrier);
   - scenario knobs: per-link relative speeds (stragglers) and per-destination
-    payload scaling (skew).
+    payload scaling (skew);
+  - a port-limited OCS (``ports`` < 2n, paper Section 3.7), below.
+
+Port-limited fabrics (the Section 3.7 blocked ring).  With z OCS ports for n
+nodes, blocks of B = ceil(2n / z) consecutive nodes [bB, bB + B) share one
+optical egress and one optical ingress port; B must divide n, so the blocks
+tile the ring.  A block's last node bB + B - 1 (its gateway) owns the
+optical egress, its first node bB the optical ingress; every other link is
+the electrical intra-block link u -> u + 1, which never rewires.  Every
+node still has one egress port and one FIFO: node u's port serves the
+electrical link to u + 1, or, on a gateway, the optical circuit.
+
+  - A segment of link offset g that is a multiple of B and larger than B
+    sets every gateway's circuit to the first node of the block g further
+    on (stride g - B + 1).  A chunk from node u (at position i = u mod B)
+    reaches u + g in exactly B hops: B - 1 - i electrical hops to its
+    block's gateway, the optical hop, then i electrical hops inside the
+    destination block.  A step of message offset o takes (o / g) * B hops,
+    `BlockedRing.effective_hops`.
+  - Any other segment (g <= B, or g not a multiple of B: no circuit of
+    whole blocks realizes it) plays on the static ring: every gateway's
+    circuit points to the next block's first node (stride 1) and a step of
+    offset o takes o hops (`subrings.blocked_hops`).
+  - The shared port's contention is played, not assumed: in each hop
+    stream of a step every port serves exactly one source's chunks (the
+    route is a permutation of the nodes), so over the B hop streams of one
+    circuit hop a gateway serves the optical traffic of all B nodes of its
+    block in turn, one after the other through its FIFO.  Per step every
+    port serves C chunks per hop, C * (o / g) * B in all: the analytic
+    congestion c = h.
+  - The circuits of a segment are its optical stride: a boundary rewires
+    (and stalls ports by ``delta * (1 - overlap)``) only where the stride
+    changes, so boundaries between static-ring segments are free.
+
+Departures from the analytic model (`simulator.collective_time(ports=)`):
+where g > B is not a multiple of B, `BlockedRing.effective_hops` reads
+(o / g) * B hops, a floor no circuit of whole blocks realizes, and the
+engine plays the static ring's o hops instead (radix-2 fabrics with B a
+power of two never meet this; radix 3 on blocks of 2, or blocks of 3 at
+radix 2, do); chunks pipeline hop by hop with ``alpha_h`` per hop and per chunk, as on
+the full-port fabric; a rewiring stalls every port, the electrical ones
+too, after the last chunk it served in the old segment (the swap gate the
+full-port model uses; every route crosses a gateway within B hops), while
+the analytic model charges R * delta flat; and a boundary that leaves the
+stride unchanged (between two static-ring segments) costs nothing, where
+the analytic model still charges it.  With ``ports`` None or >= 2n (B = 1)
+every node is a gateway, the stride is g, and every result is the
+full-port one, bit for bit.  ``ports`` applies to `run`; traces, faults and
+full-pause mode model a full-port fabric only.
 
 ``mode="full-pause"`` reproduces the legacy synchronized simulator
 bit-for-bit (it runs the exact `collective_time_event` loop), which keeps
@@ -60,6 +108,7 @@ from .cost_model import CostModel
 from .faults import (ABRUPT_KINDS, DegradedState, FaultTimeline,
                      snapshot_to_tree, world_after)
 from .schedules import Schedule, changed_links
+from .subrings import block_size, blocked_successor
 
 _MODES = ("sparse", "full-pause", "batched")
 
@@ -194,12 +243,15 @@ class FabricSim:
     payload_scale  : per-destination payload multiplier — the message a node
                      sends in a sub-step is scaled by the factor of its
                      (immediate) destination, modeling skewed payloads.
+    ports          : OCS port count; < 2n plays the Section 3.7 blocked ring
+                     (module docstring).  None = a full-port OCS.
     """
 
     def __init__(self, *, chunks_per_msg: int = 32, overlap: float = 0.0,
                  mode: str = "sparse",
                  link_speed: list[float] | None = None,
-                 payload_scale: list[float] | None = None):
+                 payload_scale: list[float] | None = None,
+                 ports: int | None = None):
         if mode not in _MODES:
             raise ValueError(f"mode must be one of {_MODES}, got {mode!r}")
         if not 0.0 <= overlap <= 1.0:
@@ -212,16 +264,32 @@ class FabricSim:
             raise ValueError(
                 "overlap requires mode='sparse' or 'batched': full-pause "
                 "always blocks the whole fabric for the full delta")
+        if ports is not None and ports < 1:
+            raise ValueError(f"ports must be >= 1, got {ports}")
         self.chunks_per_msg = max(1, int(chunks_per_msg))
         self.overlap = float(overlap)
         self.mode = mode
         self.link_speed = link_speed
         self.payload_scale = payload_scale
+        self.ports = ports
+
+    def _block(self, n: int) -> int:
+        """Block size on an n-node fabric (1 = full-port); it must tile n."""
+        block = block_size(n, self.ports)
+        if n % block:
+            raise ValueError(
+                f"ports={self.ports} gives blocks of {block} nodes, which do "
+                f"not tile n={n}")
+        return block
 
     # --- public API ----------------------------------------------------------
 
     def run(self, schedule: Schedule, m: float, cm: CostModel) -> FabricResult:
         if self.mode == "full-pause":
+            if self._block(schedule.n) > 1:
+                raise ValueError(
+                    "a port-limited fabric needs mode='sparse' or 'batched': "
+                    "full-pause is the legacy full-port baseline")
             return self._run_full_pause(schedule, m, cm)
         if self.mode == "batched":
             return self._run_batched(schedule, m, cm)
@@ -270,6 +338,10 @@ class FabricSim:
         (`repro.workloads.recovery` drives that loop).
         """
         phases = _validate_phases(phases)
+        if self._block(phases[0][0].n) > 1:
+            raise ValueError(
+                "traces are played on a full-port fabric: ports < 2n (the "
+                "Section 3.7 blocked ring) applies to single collectives")
         if self.mode == "full-pause":
             if (initial is not None or capture_state or faults is not None
                     or checkpoint_dir is not None):
@@ -490,7 +562,8 @@ class FabricSim:
             link_speed=(tuple(self.link_speed)
                         if self.link_speed is not None else None),
             payload_scale=(tuple(self.payload_scale)
-                           if self.payload_scale is not None else None))
+                           if self.payload_scale is not None else None),
+            ports=self.ports)
         return batch_run([lane], cm, chunks_per_msg=self.chunks_per_msg).result(0)
 
     # --- full-pause (legacy-compatible) mode ---------------------------------
@@ -541,7 +614,7 @@ class FabricSim:
             completion=out.completion, mode=self.mode,
             step_done=out.step_done, node_done=out.node_done,
             chunks_moved=out.chunks_moved,
-            changed_links=compile_tape(schedule).changed_links,
+            changed_links=compile_tape(schedule, self._block(schedule.n)).changed_links,
             reconfigs_paid=out.reconfigs_paid, delta_stall=out.delta_stall)
 
     def _sparse_engine(self, phases: Sequence[tuple[Schedule, float]],
@@ -561,12 +634,13 @@ class FabricSim:
         (strictly-before: a service starting exactly at the cutoff never
         left its source port)."""
         n = phases[0][0].n
-        tapes = [compile_tape(sched) for sched, _ in phases]
+        block = self._block(n)
+        tapes = [compile_tape(sched, block) for sched, _ in phases]
         offsets: list[int] = []
         hops: list[int] = []
         nbytes_step: list[float] = []
         seg_of: list[int] = []
-        seg_g: list[int] = []
+        seg_g: list[int] = []    # circuits per segment: the optical stride
         seg_hops: list[int] = []
         for (_, m), tape in zip(phases, tapes, strict=True):
             base = len(seg_g)
@@ -574,7 +648,8 @@ class FabricSim:
             hops.extend(tape.hops)
             nbytes_step.extend(m * cnt / n for cnt in tape.counts)
             seg_of.extend(base + si for si in tape.seg_of)
-            seg_g.extend(tape.seg_g)
+            seg_g.extend(tape.stride[k] for k in range(tape.S)
+                         if k == 0 or tape.boundary[k])
             seg_hops.extend(tape.seg_hops)
         S = len(offsets)
         nseg = len(seg_g)
@@ -672,9 +747,8 @@ class FabricSim:
             t_next = start + tx + alpha_h
             heapq.heappush(heap, (free[port], seq, 1, port, 0, 0, 0, 0))
             seq += 1
-            g = seg_g[si]
             if j + 1 < hops[k]:
-                nxt_port = (u + (j + 1) * g) % n
+                nxt_port = blocked_successor(port, seg_g[si], block, n)
                 heapq.heappush(heap, (t_next, seq, 0, nxt_port, k, u, c, j + 1))
                 seq += 1
             else:

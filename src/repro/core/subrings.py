@@ -153,7 +153,8 @@ class BlockedRing:
         Without port limits this is msg_offset / link_offset.  With blocks of
         size B, the optical shortcut only connects block boundaries, so the
         distance floor after any reconfiguration is B (never worse than the
-        static distance).
+        static distance).  The event engine plays this floor wherever a
+        circuit of whole blocks realizes the link offset (`blocked_hops`).
         """
         if msg_offset % link_offset:
             raise ValueError("unreachable: message offset not multiple of link offset")
@@ -163,3 +164,51 @@ class BlockedRing:
         if link_offset == 1:
             return msg_offset  # static ring: electrical path, no OCS involved
         return min(msg_offset, unconstrained * self.block_size)
+
+
+def block_size(n: int, ports: int | None) -> int:
+    """Nodes per block of a fabric with ``ports`` OCS ports (1: full-port,
+    which ``ports`` None or >= 2n gives)."""
+    return 1 if ports is None else BlockedRing(n=n, ports=ports).block_size
+
+
+def _shortens(g: int, block: int) -> bool:
+    """A circuit of whole blocks realizes link offset g and shortens a
+    step (every g on a full-port fabric, B = 1)."""
+    return block == 1 or (g > block and g % block == 0)
+
+
+def blocked_hops(offset: int, g: int, block: int) -> int:
+    """Hops the event engine plays in a step of message offset ``offset``
+    under link offset ``g`` on blocks of ``block`` nodes: B per circuit hop
+    where a circuit shortens the step (offset / g at full port), else the
+    static ring's ``offset`` (the electrical path, no OCS involved).
+
+    This is `BlockedRing.effective_hops` wherever ``g`` is a multiple of B
+    (every radix-2 fabric with B a power of two).  Where it is not, the
+    analytic floor still reads min(offset, (offset / g) * B), which no
+    circuit of whole blocks realizes; the engine keeps the static ring
+    there (a departure listed in `core.fabricsim`)."""
+    return offset // g * block if _shortens(g, block) else offset
+
+
+def blocked_circuit(g: int, block: int) -> int:
+    """Stride of the optical hop of a segment whose link offset is ``g``.
+
+    On blocks of B nodes the block's last node (its gateway) holds the
+    optical egress, and its circuit lands on the first node of the block
+    ``g`` further on: stride g - B + 1, with every other hop an electrical
+    stride-1 hop.  A segment that no circuit of whole blocks shortens (g not
+    a multiple of B, or g <= B) plays on the static ring, stride 1.  With
+    B = 1 every hop is optical and the stride is g itself.  Two segments
+    use the same circuits iff their strides are equal.
+    """
+    return g - block + 1 if _shortens(g, block) else 1
+
+
+def blocked_successor(port: int, stride: int, block: int, n: int) -> int:
+    """Node a chunk served by ``port`` goes to next: over the optical
+    circuit (``stride``) from a block's last node, else over the electrical
+    link to ``port + 1`` (B = 1: every node is a gateway)."""
+    step = stride if port % block == block - 1 else 1
+    return (port + step) % n

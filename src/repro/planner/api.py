@@ -31,6 +31,7 @@ from repro.core.jsonio import (FabricKind, RequestBase, SharingMode,
                                require_keys, require_positive_payload)
 from repro.core.schedules import Schedule
 from repro.core.simulator import TimeBreakdown
+from repro.core.subrings import block_size
 
 PlanKind = Literal["a2a", "rs", "ag", "ar"]
 PLAN_KINDS = ("a2a", "rs", "ag", "ar")
@@ -82,10 +83,12 @@ class PlanRequest(RequestBase):
                     transition delta is topology-dependent and not counted).
     delta_budget  : cap on total reconfiguration time R * delta, seconds
                     (combined with max_R; the tighter bound wins).
-    ports         : OCS port count; < 2n engages the Section 3.7 blocked-ring
-                    distance floor during evaluation (analytic fabrics only;
-                    rejected for 'ocs-sim', whose event engine models a
-                    full-port OCS).
+    ports         : OCS port count; < 2n engages the Section 3.7 blocked
+                    ring: blocks of B = ceil(2n / ports) nodes share one
+                    optical port pair.  The analytic fabrics apply its
+                    distance floor (`BlockedRing.effective_hops`);
+                    'ocs-sim' event-scores it (`core.fabricsim` describes
+                    the blocked step), which needs B to divide n.
     init_g        : link offset the fabric was left configured at by a
                     preceding collective (windowed / carryover requests, e.g.
                     the online trace planner).  Candidates are charged the
@@ -136,17 +139,22 @@ class PlanRequest(RequestBase):
             raise ValueError(
                 f"fabric='ocs-sim' event-scores total completion time only; "
                 f"objective must be 'time', got {self.objective!r}")
-        if self.fabric == FabricKind.OCS_SIM and self.ports is not None:
-            raise ValueError(
-                "fabric='ocs-sim' simulates a full-port OCS (the batch "
-                "engine has no Section 3.7 blocked-ring model); drop ports "
-                "or use the analytic 'ocs'/'ocs-overlap' fabrics")
         if self.max_R is not None and self.max_R < 0:
             raise ValueError(f"max_R must be >= 0, got {self.max_R}")
         if self.ports is not None and self.ports < 1:
             raise ValueError(f"ports must be >= 1, got {self.ports}")
+        if self.fabric == FabricKind.OCS_SIM and self.n % self.block:
+            raise ValueError(
+                f"fabric='ocs-sim' plays the Section 3.7 blocked ring on "
+                f"blocks that tile the ring: ports={self.ports} gives blocks "
+                f"of {self.block} nodes, which do not divide n={self.n}")
         if self.strategies is not None and not isinstance(self.strategies, tuple):
             object.__setattr__(self, "strategies", tuple(self.strategies))
+
+    @property
+    def block(self) -> int:
+        """Nodes per block of the request's fabric (1 = full-port)."""
+        return block_size(self.n, self.ports)
 
     def effective_max_R(self) -> int | None:
         """Tightest reconfiguration cap implied by max_R and delta_budget."""

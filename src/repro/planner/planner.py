@@ -12,7 +12,9 @@ Fabrics and scoring:
   - ``ocs-sim`` event-scores *every* candidate with the vectorized batch
     fabric engine (`core.batchsim.batch_completion_times`) in a single
     batched call — per-port queueing, chunk pipelining, and sparse
-    reconfiguration stalls that the closed-form model cannot see.  The
+    reconfiguration stalls that the closed-form model cannot see; with
+    ``ports`` < 2n on the Section 3.7 blocked ring, whose blocks share one
+    optical port each.  The
     winner is the candidate the simulator ranks fastest, so it is never a
     schedule the simulator would rank worse than the analytic winner (which
     is always in the candidate set).  The scoring call picks the JAX
@@ -58,6 +60,7 @@ from repro.core.simulator import (TimeBreakdown, allreduce_time,
                                   allreduce_time_overlap, collective_time,
                                   collective_time_overlap)
 from repro.core.spans import span
+from repro.core.subrings import blocked_circuit
 
 from .api import (Candidate, FabricKind, PlanRequest, PlanResult,
                   RankedAlternative)
@@ -147,7 +150,8 @@ class Planner:
         self._misses = 0
 
     def plan(self, req: PlanRequest) -> PlanResult:
-        with span("plan", req=next(self._requests), kind=req.kind) as sp:
+        with span("plan", req=next(self._requests), kind=req.kind,
+                  block=req.block) as sp:
             if self.cache_size == 0:
                 sp.set_metadata(hit=0)
                 return self._verified(self._plan_uncached(req))
@@ -241,7 +245,8 @@ class Planner:
             completions = batch_completion_times(
                 [cands[i].schedule for i in idx], req.m_bytes,
                 req.cost_model, overlap=req.overlap,
-                chunks_per_msg=self.sim_chunks, backend=self.sim_backend)
+                chunks_per_msg=self.sim_chunks, backend=self.sim_backend,
+                ports=req.ports)
         return {i: float(t) for i, t in zip(idx, completions, strict=True)}
 
     def _plan_collective(self, req: PlanRequest) -> PlanResult:
@@ -311,12 +316,16 @@ class Planner:
 
         Under ``ocs-sim`` the phases' predicted times are already simulated
         completions; the RS->AG topology transition is charged as a sparse
-        swap exactly as `allreduce_time_overlap` does.
+        swap exactly as `allreduce_time_overlap` does, where the circuits
+        change: on a port-limited fabric, where the optical stride does
+        (`subrings.blocked_circuit`; the link offset at full port).
         """
         if req.fabric != FabricKind.OCS_SIM:
             return _objective_score(bd, req.objective)
-        rs_final = rs_res.schedule.link_offsets()[-1]
-        ag_first = ag_res.schedule.link_offsets()[0]
+        rs_final = blocked_circuit(rs_res.schedule.link_offsets()[-1],
+                                   req.block)
+        ag_first = blocked_circuit(ag_res.schedule.link_offsets()[0],
+                                   req.block)
         changed = req.n if rs_final != ag_first else 0
         transition = req.cost_model.delta_sparse(changed, req.overlap)
         return rs_res.predicted_time + ag_res.predicted_time + transition

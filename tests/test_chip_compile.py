@@ -65,9 +65,10 @@ def _bytes_in_use(compiled) -> int:
 
 # B=32 covers the plan phase's 11-19 candidates per request (n=512, C=8,
 # the Planner's sim_chunks); B=256 is the scoring phase's whole batch
-# (n=1536, C=4), which play_certified splits into 4 buckets of 64
-@pytest.mark.parametrize("B,n,C", [(32, 512, 8), (256, 1536, 4)],
-                         ids=["plan", "scoring"])
+# (n=1536, C=4), which play_certified splits into 4 buckets of 64; B=8 is
+# the port-limited plan cell's largest padded bucket (n=256, C=8, S=8)
+@pytest.mark.parametrize("B,n,C", [(32, 512, 8), (256, 1536, 4), (8, 256, 8)],
+                         ids=["plan", "scoring", "ports"])
 def test_playback_kernel_compiles_in_float64(one_chip, B, n, C):
     import jax.numpy as jnp
 
@@ -80,8 +81,8 @@ def test_playback_kernel_compiles_in_float64(one_chip, B, n, C):
         compiled = batchsim_jax._kernel().lower(
             arg((B, S), jnp.float64), arg((B, S), jnp.int64),
             arg((B, S), jnp.int64), arg((B, S), jnp.bool_),
-            arg((B,), jnp.float64), cm.alpha_s, cm.alpha_h, cm.beta,
-            n=n, C=C).compile()
+            arg((B,), jnp.float64), arg((B,), jnp.int64),
+            cm.alpha_s, cm.alpha_h, cm.beta, n=n, C=C).compile()
     assert "while" in compiled.as_text()
     assert 0 < _bytes_in_use(compiled) < HBM_BYTES
 

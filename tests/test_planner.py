@@ -316,8 +316,16 @@ def test_ocs_sim_request_validation():
     with pytest.raises(ValueError, match="time"):
         PlanRequest(kind="a2a", n=8, m_bytes=1.0, fabric="ocs-sim",
                     objective="latency")
-    # the event engine models a full-port OCS; a ports constraint would be
-    # silently ignored, so it is rejected instead
+    # the event engine plays the Section 3.7 blocked ring: ports are
+    # accepted where the blocks tile the ring, and engage the block model
+    req = PlanRequest(kind="a2a", n=8, m_bytes=1.0, fabric="ocs-sim", ports=8)
+    assert req.block == 2
+    res = Planner().plan(PlanRequest(kind="a2a", n=8, m_bytes=MB,
+                                     fabric="ocs-sim", ports=8))
+    full = Planner().plan(PlanRequest(kind="a2a", n=8, m_bytes=MB,
+                                      fabric="ocs-sim"))
+    assert [a.predicted_time for a in res.alternatives if a.R] != \
+        [a.predicted_time for a in full.alternatives if a.R]
     with pytest.raises(ValueError, match="ports"):
         PlanRequest(kind="a2a", n=8, m_bytes=1.0, fabric="ocs-sim", ports=3)
     req = PlanRequest(kind="a2a", n=8, m_bytes=1.0, fabric="ocs-sim",

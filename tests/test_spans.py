@@ -103,9 +103,9 @@ def test_every_plan_has_a_span_with_its_request_number_kind_and_hit(planned):
     spans, _ = planned
     plans = [s for s in spans if s["name"] == "repro.plan"]
     assert [s["args"] for s in plans] == [
-        {"req": 0, "kind": "a2a", "hit": 0},
-        {"req": 1, "kind": "a2a", "hit": 1},
-        {"req": 2, "kind": "ar", "hit": 0}]
+        {"req": 0, "kind": "a2a", "block": 1, "hit": 0},
+        {"req": 1, "kind": "a2a", "block": 1, "hit": 1},
+        {"req": 2, "kind": "ar", "block": 1, "hit": 0}]
     assert all(s["parent"] is None for s in plans)
     # a hit does no planning work
     hit = spans.index(plans[1])
@@ -206,7 +206,7 @@ def test_host_playback_has_its_span():
     lanes = [BatchLane(schedule=periodic_a2a(8, 1), m_bytes=MB)]
     spans = profile_spans(lambda: batch_run(lanes, CM, backend="numpy"))
     (play,) = [s for s in spans if s["name"] == "repro.batch.host_play"]
-    assert play["args"] == {"lanes": 1}
+    assert play["args"] == {"lanes": 1, "blocked_hops": 0}
     assert not [s for s in spans if s["name"] == "repro.playback"]
 
 
@@ -253,3 +253,29 @@ def test_spans_are_inert_and_import_nothing_without_jax():
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "ok"
+
+
+def test_blocked_plan_spans_carry_block_and_blocked_hops():
+    """A plan on a port-limited fabric: ``repro.plan`` carries its block
+    size and ``repro.playback`` the hops the block floor adds."""
+    req = PlanRequest(kind="a2a", n=16, m_bytes=2 * MB, cost_model=CM,
+                      fabric=FabricKind.OCS_SIM, ports=16)
+    results = []
+    real = batchsim.batch_run
+
+    def recording(*args, **kwargs):
+        results.append(real(*args, **kwargs))
+        return results[-1]
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(batchsim, "batch_run", recording)
+        spans = profile_spans(lambda: Planner(
+            sim_chunks=CHUNKS, sim_backend="jax").plan(req))
+    (plan,) = [s for s in spans if s["name"] == "repro.plan"]
+    assert plan["args"]["block"] == 2
+    (play,) = [s for s in spans if s["name"] == "repro.playback"]
+    (res,) = results
+    tapes = [compile_tape(lane.schedule, 2) for lane in res.lanes]
+    added = sum(h - off // g for t in tapes
+                for h, off, g in zip(t.hops, t.offsets, t.g_step))
+    assert play["args"]["blocked_hops"] == added > 0
